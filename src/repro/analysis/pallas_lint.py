@@ -8,13 +8,13 @@ compiling anything) and then *statically evaluates* the launch:
 * ``PAL-OOB``: every ``BlockSpec.index_map`` is enumerated over the full
   grid (with the real scalar-prefetch operands bound) and each returned
   block index must satisfy ``0 <= bi < cdiv(dim, block)`` — the proof
-  that no tile reads or writes outside its operand. This is exactly the
-  class of bug interpret-mode hides (OOB reads clamp) and hardware
-  corrupts silently.
-* ``PAL-ALIGN``: MXU/VREG tiling — a block's last dim must be a multiple
-  of 128 (or cover the whole axis), its second-to-last a multiple of 8
-  (or be 1, or cover the axis). Misaligned tiles compile but pad in VMEM,
-  quietly wasting the systolic array.
+  that no tile reads or writes outside its operand. (A partial last block
+  inside that range is legal; its padding holds garbage on the chip, so
+  the kernel must mask it — the kernels' partial-tile tests cover that.)
+* ``PAL-ALIGN``: the TPU lowering's tiling rule — a block's last dim
+  must be a multiple of 128 and its second-to-last a multiple of 8, each
+  unless it covers the whole axis. Mosaic refuses any other block, so a
+  kernel that breaks the rule only ever ran in interpret mode.
 * ``PAL-PREFETCH``: small integer control vectors (per-slot offsets,
   ragged counts) must ride ``num_scalar_prefetch`` — as blocked operands
   they'd serialize the grid on VMEM loads the indexing depends on; and
@@ -125,7 +125,7 @@ def verify_record(name: str, rec) -> List[Finding]:
                 f"{last} is neither lane-aligned (x128) nor the full axis"))
         if len(concrete) >= 2:
             sub, sdim = concrete[-2], shape[-2]
-            if sub % 8 != 0 and sub != 1 and sub != sdim:
+            if sub % 8 != 0 and sub != sdim:
                 finds.append(Finding(
                     "PAL-ALIGN", tgt,
                     f"{kind}_spec block {concrete} on {list(shape)}: "

@@ -27,6 +27,7 @@ from typing import List
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend import core as jax_core
 
 from repro.analysis.framework import (Finding, constrained_downstream,
                                       derives_from_invar, eqn_site, walk_eqns)
@@ -56,7 +57,7 @@ def _cache_writes(bundle, name: str) -> List[Finding]:
             continue                     # scratch value, not a live buffer
         idx = eqn.invars[1:] if eqn.primitive.name.startswith("scatter") \
             else eqn.invars[2:]
-        if all(isinstance(v, jax.core.Literal) for v in idx):
+        if all(isinstance(v, jax_core.Literal) for v in idx):
             continue                     # static write: XLA sees through it
         out = eqn.outvars[0]
         if constrained_downstream(out, owner):

@@ -6,7 +6,6 @@ config for CPU tests) in its module, and registers itself in REGISTRY.
 """
 from __future__ import annotations
 
-import dataclasses
 import math
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
@@ -76,20 +75,11 @@ class ModelConfig:
     n_image_tokens: int = 0         # patch tokens from the (stubbed) frontend
     d_frontend: int = 0             # frontend embedding dim (projected to d_model)
     dtype: str = "bfloat16"
-    # TP head padding: q-heads are zero-padded (exact — wo pad rows are 0) to
-    # a multiple of this so the head dim divides the `model` mesh axis.
-    # full configs use 16 (set centrally in get_config); smoke/toy keep 1.
-    head_pad: int = 1
 
     # ---- derived ----
     @property
     def padded_vocab(self) -> int:
         return _round_up(self.vocab_size, 256)
-
-    @property
-    def n_heads_p(self) -> int:
-        """q-heads padded for TP divisibility (zero heads, exact)."""
-        return _round_up(self.n_heads, self.head_pad)
 
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
@@ -246,18 +236,8 @@ def default_elastic(cfg: ModelConfig) -> ElasticConfig:
     )
 
 
-TP_HEAD_PAD = 16   # production `model` mesh axis size
-
-
 def get_config(name: str, variant: str = "full") -> ModelConfig:
-    cfg = REGISTRY[name][variant]()
-    if variant == "full" and not name.startswith("toy") and cfg.head_pad == 1:
-        cfg = dataclasses.replace(cfg, head_pad=TP_HEAD_PAD)
-        if cfg.encoder is not None:
-            cfg = dataclasses.replace(
-                cfg, encoder=dataclasses.replace(cfg.encoder,
-                                                 head_pad=TP_HEAD_PAD))
-    return cfg
+    return REGISTRY[name][variant]()
 
 
 def get_elastic(name: str, cfg: Optional[ModelConfig] = None) -> ElasticConfig:

@@ -348,9 +348,9 @@ def ragged_bucket(policy: Optional[ElasticPolicy], s: int,
 def stack_flops_per_token(cfg, spec: ElasticSpec, *, ctx: int = 1024):
     """Analytic per-token forward FLOPs, split into (fixed, routed) parts.
 
-    Same analytic model as ``launch/dryrun.model_flops`` (parameter matmuls
-    at 2 FLOPs/MAC plus the quadratic attention term at average context
-    ``ctx``), but decomposed per elastic knob so a budget can be solved for.
+    Parameter matmuls at 2 FLOPs/MAC plus the quadratic attention term at
+    average context ``ctx``, decomposed per elastic knob so a budget can be
+    solved for.
 
     ``routed`` maps knob name -> FLOPs that scale with that knob's fraction.
     Token capacities and head/expert fractions COMPOSE multiplicatively on

@@ -1,10 +1,9 @@
 from repro.kernels.ops import (decode_attention, flash_attention, fused_mlp,
-                               fused_mlp_routed, moe_gmm,
-                               paged_decode_attention, resolve_backend)
+                               moe_gmm, paged_decode_attention,
+                               resolve_backend)
 
-__all__ = ["decode_attention", "flash_attention", "fused_mlp",
-           "fused_mlp_routed", "moe_gmm", "paged_decode_attention",
-           "resolve_backend", "analyzable_kernels"]
+__all__ = ["decode_attention", "flash_attention", "fused_mlp", "moe_gmm",
+           "paged_decode_attention", "resolve_backend", "analyzable_kernels"]
 
 
 def analyzable_kernels() -> dict:
@@ -23,7 +22,6 @@ def analyzable_kernels() -> dict:
     return {
         "flash_attention": _fa.analysis_example,
         "fused_mlp": _fm.analysis_example,
-        "fused_mlp_routed": _fm.analysis_example_routed,
         "moe_gmm": _mg.analysis_example,
         "decode_attention": _da.analysis_example,
         "paged_decode_attention": _pd.analysis_example,

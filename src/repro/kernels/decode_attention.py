@@ -30,9 +30,6 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 LANES = 128
 
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 
 def analysis_example():
     """Representative ring-cache decode call for the static kernel
@@ -68,30 +65,33 @@ def _kernel(t_ref, q_ref, k_ref, v_ref, pos_ref, valid_ref, ks_ref, vs_ref,
 
     q = q_ref[0, 0].astype(jnp.float32)                  # (1, d)
     k = k_ref[0, 0].astype(jnp.float32)                  # (bk, d)
-    if ks_ref is not None:
-        # int8 cache: widen in-register, per-(slot, kv-head) f32 scale —
-        # HBM only ever saw the int8 tile (docs/quantization.md)
-        k = k * ks_ref[0, 0][:, None]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     s = s * sm_scale                                      # (1, bk)
-    pos = pos_ref[0][None, :]                             # (1, bk) i32
+    if ks_ref is not None:
+        # int8 cache: each key row's per-(slot, kv-head) f32 scale folds
+        # into its score column, so the widened tile needs no per-row
+        # broadcast — HBM only ever saw the int8 tile (docs/quantization.md)
+        s = s * ks_ref[0, 0]
+    pos = pos_ref[0]                                      # (1, bk) i32
     mask = (pos >= 0) & (pos <= t)
     if window and window > 0:
         mask &= (t - pos) < window
     if valid_ref is not None:
-        mask &= valid_ref[0][None, :] > 0
+        mask &= valid_ref[0] > 0
     s = jnp.where(mask, s, NEG_INF)
     m_prev = m_sc[:, 0]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
+    # masked keys get probability exactly 0 — also in a block where every
+    # key is masked (there s - m_new == 0), so a row with no attendable
+    # key keeps l == 0 and finishes as exact zeros
+    p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
     l_sc[:, 0] = l_sc[:, 0] * alpha + jnp.sum(p, axis=1)
     m_sc[:, 0] = m_new
     v = v_ref[0, 0].astype(jnp.float32)
     if vs_ref is not None:
-        v = v * vs_ref[0, 0][:, None]
-    v = jnp.where(mask[0][:, None], v, 0.0)   # masked rows: 0 * NaN guard
+        p = p * vs_ref[0, 0]       # value-row scales fold into p's columns
     acc_sc[...] = acc_sc[...] * alpha[:, None] + jax.lax.dot(
         p, v, preferred_element_type=jnp.float32)
 
@@ -137,23 +137,28 @@ def decode_attention(q, k, v, kv_pos, t, *, window: int = 0, kv_valid=None,
 
     kernel = functools.partial(_kernel, window=window, sm_scale=sm_scale,
                                n_kb=nkb)
+    # per-slot rows ride as (B, 1, L) so every block's last two dims are
+    # (1 == full axis, bk) — the TPU tiling rule for (8, 128) blocks
+    row_spec = pl.BlockSpec((1, 1, bk), lambda b, h, j, *_: (b, 0, j))
     in_specs = [
         pl.BlockSpec((1, 1, 1, Dh), lambda b, h, j, *_: (b, h, 0, 0)),
         pl.BlockSpec((1, 1, bk, Dh), lambda b, h, j, *_: (b, h // G, j, 0)),
         pl.BlockSpec((1, 1, bk, Dh), lambda b, h, j, *_: (b, h // G, j, 0)),
-        pl.BlockSpec((1, bk), lambda b, h, j, *_: (b, j)),
+        row_spec,
     ]
-    args = [qt, kt, vt, pos]
+    args = [qt, kt, vt, pos[:, None, :]]
     have_valid = kv_valid is not None
     if have_valid:
-        in_specs.append(pl.BlockSpec((1, bk), lambda b, h, j, *_: (b, j)))
-        args.append(kv_valid.astype(jnp.int32))
+        in_specs.append(row_spec)
+        args.append(kv_valid.astype(jnp.int32)[:, None, :])
     if quantized:
-        # scales ride as regular VMEM blocks, head-major like k/v
-        sspec = pl.BlockSpec((1, 1, bk), lambda b, h, j, *_: (b, h // G, j))
+        # scales ride as regular VMEM blocks, head-major like k/v:
+        # (B, K, 1, L) rows, one (1, bk) lane block per kv-head
+        sspec = pl.BlockSpec((1, 1, 1, bk),
+                             lambda b, h, j, *_: (b, h // G, 0, j))
         in_specs += [sspec, sspec]
-        args += [kscale.astype(jnp.float32).transpose(0, 2, 1),
-                 vscale.astype(jnp.float32).transpose(0, 2, 1)]
+        args += [kscale.astype(jnp.float32).transpose(0, 2, 1)[:, :, None],
+                 vscale.astype(jnp.float32).transpose(0, 2, 1)[:, :, None]]
 
     def kfn(t_ref, q_ref, k_ref, v_ref, pos_ref, *rest):
         rs = list(rest)
@@ -176,9 +181,10 @@ def decode_attention(q, k, v, kv_pos, t, *, window: int = 0, kv_valid=None,
     )
     out = pl.pallas_call(
         kfn,
+        name="decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, Dh), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(t, *args)

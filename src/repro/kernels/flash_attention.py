@@ -35,9 +35,6 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 LANES = 128
 
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 
 def analysis_example():
     """Representative call for the static kernel verifier
@@ -96,7 +93,7 @@ def _kernel(cnt_ref, valid_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc,
         if window and window > 0:
             mask &= (qpos - kpos) < window
         if valid_ref is not None:
-            mask &= valid_ref[0][None, :] > 0
+            mask &= valid_ref[0] > 0                      # (1, bk) row
         s = jnp.where(mask, s, NEG_INF)
         m_prev = m_sc[:, 0]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
@@ -156,8 +153,11 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     ]
     args = [qt, kt, vt]
     if kv_valid is not None:
-        in_specs.insert(0, pl.BlockSpec((1, bk), lambda b, h, i, j, *_: (b, j)))
-        args.insert(0, kv_valid.astype(jnp.int32))
+        # (B, 1, Sk) rows: the block's last two dims are (1 == full axis,
+        # bk) — the TPU tiling rule for (8, 128) blocks
+        in_specs.insert(0, pl.BlockSpec((1, 1, bk),
+                                        lambda b, h, i, j, *_: (b, 0, j)))
+        args.insert(0, kv_valid.astype(jnp.int32)[:, None, :])
         kfn = kernel
     else:
         kfn = lambda cnt_ref, *rest: kernel(cnt_ref, None, *rest)
@@ -176,9 +176,10 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     )
     out = pl.pallas_call(
         kfn,
+        name="flash_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, Dh), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

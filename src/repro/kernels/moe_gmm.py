@@ -26,8 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
+from repro.kernels.fused_mlp import down_proj, ffn_hidden, vmem_limit
 
 
 def analysis_example():
@@ -47,13 +46,17 @@ def analysis_example():
 
 
 def _kernel(cnt_ref, x_ref, wi_ref, wg_ref, wo_ref, w_ref, wis_ref, wgs_ref,
-            wos_ref, o_ref, acc_sc, *, act: str, n_fb: int, block_c: int):
+            wos_ref, o_ref, acc_sc, *, act: str, n_fb: int, block_c: int,
+            block_f: int, f_total: int):
     ib = pl.program_id(0)
     ie = pl.program_id(1)
     ic = pl.program_id(2)
     jf = pl.program_id(3)
     cnt = cnt_ref[ib, ie]
     live = ic * block_c < cnt
+    # an expert width that is not a multiple of the tile (qwen2-7b moefied
+    # 16 ways: 1184) leaves a partial last tile, masked inside
+    f_left = (f_total - jf * block_f) if f_total % block_f else None
 
     @pl.when(jnp.logical_not(live) & (jf == n_fb - 1))
     def _dead():  # capacity tile past this expert's occupancy: zeros only
@@ -65,31 +68,18 @@ def _kernel(cnt_ref, x_ref, wi_ref, wg_ref, wo_ref, w_ref, wis_ref, wgs_ref,
         def _init():
             acc_sc[...] = jnp.zeros_like(acc_sc)
 
-        x = x_ref[0, 0].astype(jnp.float32)                    # (bc, D)
-        wi = wi_ref[0].astype(jnp.float32)
-        if wis_ref is not None:
-            # int8 expert weights: widen in-register, per-(expert, output
-            # channel) f32 scale — HBM only ever saw the int8 tile
-            wi = wi * wis_ref[0, 0][None, :]
-        hi = jax.lax.dot(x, wi, preferred_element_type=jnp.float32)
-        if wg_ref is not None:
-            wg = wg_ref[0].astype(jnp.float32)
-            if wgs_ref is not None:
-                wg = wg * wgs_ref[0, 0][None, :]
-            hg = jax.lax.dot(x, wg, preferred_element_type=jnp.float32)
-            a = jax.nn.silu(hg) if act == "swiglu" else jax.nn.gelu(hg)
-            h = a * hi
-        else:
-            h = jax.nn.gelu(hi) if act == "gelu" else jax.nn.silu(hi)
-        wo = wo_ref[0].astype(jnp.float32)
-        if wos_ref is not None:
-            wo = wo * wos_ref[0, 0][None, :]
-        acc_sc[...] += jax.lax.dot(h, wo,
-                                   preferred_element_type=jnp.float32)
+        x = x_ref[0, 0]                                        # (bc, D)
+        at0 = lambda r: None if r is None else r[0]
+        h = ffn_hidden(x, wi_ref[0], at0(wg_ref), at0(wis_ref),
+                       at0(wgs_ref), act=act, f_left=f_left)
+        acc_sc[...] += down_proj(h, wo_ref[0], x.dtype, f_left)
 
         @pl.when(jf == n_fb - 1)
         def _finish():
-            y = acc_sc[...] * w_ref[0, 0].astype(jnp.float32)[:, :1]
+            y = acc_sc[...]
+            if wos_ref is not None:    # wo's per-(expert, D-column) scale
+                y = y * wos_ref[0]
+            y = y * w_ref[0, 0].astype(jnp.float32)[:, :1]
             rows = ic * block_c + jax.lax.broadcasted_iota(
                 jnp.int32, y.shape, 0)
             y = jnp.where(rows < cnt, y, 0.0)
@@ -97,7 +87,7 @@ def _kernel(cnt_ref, x_ref, wi_ref, wg_ref, wo_ref, w_ref, wis_ref, wgs_ref,
 
 
 def moe_gmm(x, wi, wo, wg=None, weights=None, *, act: str = "swiglu",
-            block_c: int = 128, block_f: int = 512, group_counts=None,
+            block_c: int = 128, block_f: int = 256, group_counts=None,
             wi_scale=None, wo_scale=None, wg_scale=None,
             interpret: bool = False):
     """x: (E, C, D) or batched (B, E, C, D) dispatched tokens; wi/wg:
@@ -127,7 +117,8 @@ def moe_gmm(x, wi, wo, wg=None, weights=None, *, act: str = "swiglu",
     have_g = wg is not None
     qw = wi_scale is not None
 
-    kernel = functools.partial(_kernel, act=act, n_fb=nf, block_c=bc)
+    kernel = functools.partial(_kernel, act=act, n_fb=nf, block_c=bc,
+                               block_f=bf, f_total=Fe)
     in_specs = [
         pl.BlockSpec((1, 1, bc, D), lambda b, e, i, j, *_: (b, e, i, 0)),
         pl.BlockSpec((1, D, bf), lambda b, e, i, j, *_: (e, 0, j)),
@@ -173,13 +164,18 @@ def moe_gmm(x, wi, wo, wg=None, weights=None, *, act: str = "swiglu",
                                lambda b, e, i, j, *_: (b, e, i, 0)),
         scratch_shapes=[pltpu.VMEM((bc, D), jnp.float32)],
     )
+    blocks = (2 * bc * D * x.dtype.itemsize           # x and out
+              + (3 if have_g else 2) * D * bf * wi.dtype.itemsize
+              + bc * 128 * 4)
     out = pl.pallas_call(
         kfn,
+        name="moe_gmm",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, E, C, D), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+                                 "arbitrary"),
+            vmem_limit_bytes=vmem_limit(blocks, bc * D * 4)),
         interpret=interpret,
     )(cnt, *args)
     return out[0] if squeeze else out

@@ -3,9 +3,9 @@
 The model hot path dispatches through these wrappers under a *backend*
 resolved from ``ElasticSpec.kernel_backend``:
 
-  * ``"pallas"``    — real pallas_call (TPU; falls back to the interpreter
-                      when the host has no TPU, so the same graph traces
-                      everywhere);
+  * ``"pallas"``    — real pallas_call, lowered by Mosaic for the TPU;
+                      refused on any other backend, so a kernel never runs
+                      interpreted under the name of the device path;
   * ``"interpret"`` — pallas_call under interpret=True (CPU verification of
                       the exact kernel logic, incl. the scalar-prefetch
                       ragged skip paths);
@@ -31,7 +31,9 @@ from __future__ import annotations
 from functools import partial
 
 import jax
+import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 import repro.kernels.decode_attention as _decode_mod
 import repro.kernels.flash_attention as _flash_mod
@@ -54,12 +56,16 @@ def resolve_backend(name=None) -> str:
     if name not in BACKENDS:
         raise ValueError(f"kernel_backend must be one of {BACKENDS} or "
                          f"'auto', got {name!r}")
+    if name == "pallas" and not _on_tpu():
+        raise ValueError(
+            f"kernel_backend='pallas' lowers the kernels for a TPU, but JAX's "
+            f"default backend is {jax.default_backend()!r}; use 'interpret' "
+            f"to run the kernel logic there")
     return name
 
 
 def _interp(backend: str) -> bool:
-    # "pallas" off-TPU still runs the kernel, interpreted: one code path
-    return backend == "interpret" or not _on_tpu()
+    return backend == "interpret"
 
 
 def _f0(x):
@@ -99,11 +105,10 @@ def _flash_vjp_bwd(causal, window, backend, res, g):
 _flash_fwd_op.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
-@partial(jax.jit, static_argnames=("causal", "window", "force_pallas",
-                                   "backend"))
+@partial(jax.jit, static_argnames=("causal", "window", "backend"))
 def flash_attention(q, k, v, kv_valid=None, kv_count=None, *, causal=True,
-                    window=0, force_pallas=False, backend=None):
-    kb = "pallas" if force_pallas else resolve_backend(backend)
+                    window=0, backend=None):
+    kb = resolve_backend(backend)
     if kb == "ref":
         return ref.flash_attention_ref(q, k, v, causal=causal, window=window,
                                        kv_valid=kv_valid, kv_count=kv_count)
@@ -145,11 +150,11 @@ def _fused_mlp_vjp_bwd(act, backend, res, g):
 _fused_mlp_op.defvjp(_fused_mlp_vjp_fwd, _fused_mlp_vjp_bwd)
 
 
-@partial(jax.jit, static_argnames=("act", "force_pallas", "backend"))
+@partial(jax.jit, static_argnames=("act", "backend"))
 def fused_mlp(x, wi, wo, wg=None, token_weights=None, valid_count=None,
               wi_scale=None, wo_scale=None, wg_scale=None, *,
-              act="swiglu", force_pallas=False, backend=None):
-    kb = "pallas" if force_pallas else resolve_backend(backend)
+              act="swiglu", backend=None):
+    kb = resolve_backend(backend)
     if kb == "ref":
         return ref.fused_mlp_ref(x, wi, wo, wg, token_weights, act=act,
                                  valid_count=valid_count, wi_scale=wi_scale,
@@ -162,67 +167,6 @@ def fused_mlp(x, wi, wo, wg=None, token_weights=None, valid_count=None,
             wi_scale=wi_scale, wo_scale=wo_scale, wg_scale=wg_scale,
             interpret=_interp(kb))
     return _fused_mlp_op(act, kb, x, wi, wo, wg, token_weights, valid_count)
-
-
-# ---------------------------- routed fused MLP -------------------------------
-
-@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _fused_mlp_routed_op(act, backend, x, idx, wi, wo, wg, tw, cnt):
-    return _fused_mlp_mod.fused_mlp_routed(x, idx, wi, wo, wg, tw, act=act,
-                                           valid_count=cnt,
-                                           interpret=_interp(backend))
-
-
-def _fused_mlp_routed_vjp_fwd(act, backend, x, idx, wi, wo, wg, tw, cnt):
-    out = _fused_mlp_routed_op(act, backend, x, idx, wi, wo, wg, tw, cnt)
-    return out, (x, idx, wi, wo, wg, tw, cnt)
-
-
-def _fused_mlp_routed_vjp_bwd(act, backend, res, g):
-    x, idx, wi, wo, wg, tw, cnt = res
-    diff = tuple(a for a in (x, wi, wo, wg, tw) if a is not None)
-
-    def f(*args):
-        it = iter(args)
-        a = [next(it) if v is not None else None
-             for v in (x, wi, wo, wg, tw)]
-        return ref.fused_mlp_routed_ref(a[0], idx, a[1], a[2], a[3], a[4],
-                                        act=act, valid_count=cnt)
-
-    _, vjp = jax.vjp(f, *diff)
-    grads = iter(vjp(g))
-    out = [next(grads) if v is not None else None
-           for v in (x, wi, wo, wg, tw)]
-    return (out[0], _f0(idx), *out[1:],
-            None if cnt is None else _f0(cnt))
-
-
-_fused_mlp_routed_op.defvjp(_fused_mlp_routed_vjp_fwd,
-                            _fused_mlp_routed_vjp_bwd)
-
-
-@partial(jax.jit, static_argnames=("act", "force_pallas", "backend"))
-def fused_mlp_routed(x, idx, wi, wo, wg=None, token_weights=None,
-                     valid_count=None, wi_scale=None, wo_scale=None,
-                     wg_scale=None, *, act="swiglu", force_pallas=False,
-                     backend=None):
-    """Gather/scatter-fused routed MLP: x (B,S,D) full stream, idx (B,Kb)
-    RoutingPlan indices; returns the (B,S,D) delta (see fused_mlp.py)."""
-    kb = "pallas" if force_pallas else resolve_backend(backend)
-    if kb == "ref":
-        return ref.fused_mlp_routed_ref(x, idx, wi, wo, wg, token_weights,
-                                        act=act, valid_count=valid_count,
-                                        wi_scale=wi_scale,
-                                        wo_scale=wo_scale,
-                                        wg_scale=wg_scale)
-    if wi_scale is not None:
-        # serving-only int8 path: no VJP (see fused_mlp above)
-        return _fused_mlp_mod.fused_mlp_routed(
-            x, idx, wi, wo, wg, token_weights, act=act,
-            valid_count=valid_count, wi_scale=wi_scale, wo_scale=wo_scale,
-            wg_scale=wg_scale, interpret=_interp(kb))
-    return _fused_mlp_routed_op(act, kb, x, idx, wi, wo, wg, token_weights,
-                                valid_count)
 
 
 # --------------------------------- MoE GMM -----------------------------------
@@ -259,11 +203,11 @@ def _moe_gmm_vjp_bwd(act, backend, res, g):
 _moe_gmm_op.defvjp(_moe_gmm_vjp_fwd, _moe_gmm_vjp_bwd)
 
 
-@partial(jax.jit, static_argnames=("act", "force_pallas", "backend"))
+@partial(jax.jit, static_argnames=("act", "backend"))
 def moe_gmm(x, wi, wo, wg=None, weights=None, group_counts=None,
             wi_scale=None, wo_scale=None, wg_scale=None, *,
-            act="swiglu", force_pallas=False, backend=None):
-    kb = "pallas" if force_pallas else resolve_backend(backend)
+            act="swiglu", backend=None):
+    kb = resolve_backend(backend)
     if kb == "ref":
         return ref.moe_gmm_ref(x, wi, wo, wg, weights, act=act,
                                group_counts=group_counts, wi_scale=wi_scale,
@@ -279,14 +223,13 @@ def moe_gmm(x, wi, wo, wg=None, weights=None, group_counts=None,
 
 # ----------------------------- decode attention ------------------------------
 
-@partial(jax.jit, static_argnames=("window", "force_pallas", "backend"))
+@partial(jax.jit, static_argnames=("window", "backend"))
 def decode_attention(q, k, v, kv_pos, t, kv_valid=None, kscale=None,
-                     vscale=None, *, window=0, force_pallas=False,
-                     backend=None):
+                     vscale=None, *, window=0, backend=None):
     """Ring-cache decode attention (see kernels/decode_attention.py).
     kscale/vscale: (B, L, K) f32 dequant scales for int8 k/v caches.
     Inference-only: no VJP (decode is never differentiated)."""
-    kb = "pallas" if force_pallas else resolve_backend(backend)
+    kb = resolve_backend(backend)
     if kb == "ref":
         return ref.decode_attention_ref(q, k, v, kv_pos, t, window=window,
                                         kv_valid=kv_valid, kscale=kscale,
@@ -299,13 +242,13 @@ def decode_attention(q, k, v, kv_pos, t, kv_valid=None, kscale=None,
 
 # -------------------------- paged decode attention ---------------------------
 
-@partial(jax.jit, static_argnames=("force_pallas", "backend"))
+@partial(jax.jit, static_argnames=("backend",))
 def paged_decode_attention(q, kp, vp, table, t, pvalid, kscale=None,
-                           vscale=None, *, force_pallas=False, backend=None):
+                           vscale=None, *, backend=None):
     """Paged-pool decode attention (see kernels/paged_decode_attention.py).
     kscale/vscale: (N, ps, K) f32 dequant scale pools for int8 kp/vp.
     Inference-only: no VJP (decode is never differentiated)."""
-    kb = "pallas" if force_pallas else resolve_backend(backend)
+    kb = resolve_backend(backend)
     if kb == "ref":
         return ref.paged_decode_attention_ref(q, kp, vp, table, t, pvalid,
                                               kscale=kscale, vscale=vscale)
@@ -316,24 +259,151 @@ def paged_decode_attention(q, kp, vp, table, t, pvalid, kscale=None,
 
 # --------------------------- SPMD kernel wrappers -----------------------------
 #
-# A pallas_call is a custom call — OPAQUE to GSPMD, which would replicate
-# its operands to every device (an all-gather of the whole KV cache per
-# decode step at production scale). Under a mesh the kernel entry points
+# A pallas_call is a custom call — OPAQUE to GSPMD, and Mosaic refuses to
+# let it be auto-partitioned at all. Under a mesh the kernel entry points
 # below therefore run the kernel INSIDE shard_map: each shard's grid covers
 # only its local block (heads/kv-heads or the FFN dim over `model`, batch
 # over the data axes), which is exactly how the kernels lower on a real TPU
-# slice. The jnp "ref" backend needs none of this — XLA partitions jnp ops
-# natively — so these wrappers fall through to the plain call for "ref",
-# for trivial meshes, and for shapes that don't divide the axes.
+# slice. An axis whose dims do not divide is replicated instead: every
+# device then runs the kernel on the whole of that axis (correct, and
+# slower). The jnp "ref" backend needs none of this — XLA partitions jnp
+# ops natively — so these wrappers fall through to the plain call for
+# "ref", off-mesh, on a one-device mesh, and inside an enclosing manual
+# shard_map region.
 
-def _mesh_layout(mesh):
-    """(mesh, batch_axes, data_size, model_size) for the active/given mesh."""
+
+def _shard_axes(mesh, kb, batch_dims, model_dims):
+    """(mesh, data axes or None, `model` or None) for a per-shard kernel
+    call, or None for a plain call (see the section comment). The data
+    axes shard when the mesh's data size divides every ``batch_dims``
+    entry, `model` when its size divides every ``model_dims`` entry."""
     from repro.runtime import sharding as SH
     mesh = mesh if mesh is not None else SH.active_mesh()
-    if mesh is None:
-        return None, (), 1, 1
-    return (mesh, SH.batch_axes(mesh), SH.data_axis_size(mesh),
-            mesh.shape.get("model", 1))
+    if (mesh is None or kb == "ref" or mesh.devices.size == 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return None
+    d, m = SH.data_axis_size(mesh), mesh.shape.get("model", 1)
+    bx = (SH.batch_axes(mesh)
+          if d > 1 and all(n % d == 0 for n in batch_dims) else None)
+    md = "model" if m > 1 and all(n % m == 0 for n in model_dims) else None
+    return mesh, bx, md
+
+
+def _per_shard(call, operands, out_spec, mesh, psum_axis=None):
+    """``call(**operands)`` inside shard_map. ``operands``: name ->
+    (array or None, PartitionSpec); None operands stay None in the body.
+    ``psum_axis``: reduce the per-shard partial results over it."""
+    names = [n for n, (a, _) in operands.items() if a is not None]
+
+    def body(*xs):
+        y = call(**dict(zip(names, xs)))
+        return jax.lax.psum(y, psum_axis) if psum_axis else y
+
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=tuple(operands[n][1] for n in names),
+        out_specs=out_spec, check_vma=False,
+    )(*(operands[n][0] for n in names))
+
+
+def _count_spec(cnt, bx):
+    """A valid count is () — replicated — or per batch row (B,)."""
+    return P(bx) if getattr(cnt, "ndim", 0) else P()
+
+
+def flash_attention_sharded(q, k, v, kv_valid=None, kv_count=None, *,
+                            causal=True, window=0, backend=None, mesh=None):
+    """Prefill flash attention, one grid PER SHARD: q heads and kv heads
+    over `model` (each shard's local head->kv-group mapping is then exact,
+    as in the decode wrapper), batch over the data axes. Differentiable
+    through the inner op's ref-replay VJP."""
+    kb = resolve_backend(backend)
+    lay = _shard_axes(mesh, kb, (q.shape[0],), (q.shape[2], k.shape[2]))
+    if lay is None:
+        return flash_attention(q, k, v, kv_valid, kv_count, causal=causal,
+                               window=window, backend=backend)
+    mesh, bx, md = lay
+    heads = P(bx, None, md, None)
+    return _per_shard(
+        lambda q, k, v, kv_valid=None, cnt=None: _flash_fwd_op(
+            causal, window, kb, q, k, v, kv_valid, cnt),
+        {"q": (q, heads), "k": (k, heads), "v": (v, heads),
+         "kv_valid": (kv_valid, P(bx, None)),
+         "cnt": (kv_count, _count_spec(kv_count, bx))},
+        heads, mesh)
+
+
+def fused_mlp_sharded(x, wi, wo, wg=None, token_weights=None,
+                      valid_count=None, wi_scale=None, wo_scale=None,
+                      wg_scale=None, *, act="swiglu", backend=None,
+                      mesh=None):
+    """Fused MLP with the FFN dim sharded over `model` (the dense-MLP TP
+    rules: wi/wg (D, F/m), wo (F/m, D)): each shard runs the kernel on its
+    slice of F and the partial outputs are psummed; batch rows over the
+    data axes. x: (B, T, D) or (T, D)."""
+    kb = resolve_backend(backend)
+    batched = x.ndim == 3
+    lay = _shard_axes(mesh, kb, (x.shape[0],) if batched else (),
+                      (wi.shape[-1],))
+    if lay is None:
+        return fused_mlp(x, wi, wo, wg, token_weights, valid_count, wi_scale,
+                         wo_scale, wg_scale, act=act, backend=backend)
+    mesh, bx, md = lay
+    rows = P(bx, None, None) if batched else P(None, None)
+    tw_spec = (P(bx, None) if getattr(token_weights, "ndim", 0) == 2
+               else P(None))
+
+    def call(x, wi, wo, wg=None, tw=None, cnt=None, wis=None, wos=None,
+             wgs=None):
+        if wis is not None:     # serving-only int8 path: no VJP
+            return _fused_mlp_mod.fused_mlp(
+                x, wi, wo, wg, tw, act=act, valid_count=cnt, wi_scale=wis,
+                wo_scale=wos, wg_scale=wgs, interpret=_interp(kb))
+        return _fused_mlp_op(act, kb, x, wi, wo, wg, tw, cnt)
+
+    # per-output-channel scales shard with their weight's output axis:
+    # wi/wg scales (F,) over `model`, wo scale (D,) replicated
+    return _per_shard(call, {
+        "x": (x, rows), "wi": (wi, P(None, md)), "wo": (wo, P(md, None)),
+        "wg": (wg, P(None, md)), "tw": (token_weights, tw_spec),
+        "cnt": (valid_count, _count_spec(valid_count, bx)),
+        "wis": (wi_scale, P(md)), "wos": (wo_scale, P(None)),
+        "wgs": (wg_scale, P(md))}, rows, mesh, psum_axis=md)
+
+
+def moe_gmm_sharded(x, wi, wo, wg=None, weights=None, group_counts=None,
+                    wi_scale=None, wo_scale=None, wg_scale=None, *,
+                    act="swiglu", backend=None, mesh=None):
+    """Grouped expert matmul with each expert's FFN dim sharded over
+    `model` (the MoE TP rules: wi/wg (E, D, Fe/m), wo (E, Fe/m, D)): each
+    shard runs the kernel on its slice and the partial outputs are
+    psummed; dispatch-buffer rows over the data axes. x: (B, E, C, D) or
+    (E, C, D)."""
+    kb = resolve_backend(backend)
+    batched = x.ndim == 4
+    lay = _shard_axes(mesh, kb, (x.shape[0],) if batched else (),
+                      (wi.shape[-1],))
+    if lay is None:
+        return moe_gmm(x, wi, wo, wg, weights, group_counts, wi_scale,
+                       wo_scale, wg_scale, act=act, backend=backend)
+    mesh, bx, md = lay
+    lead = (bx,) if batched else ()
+
+    def call(x, wi, wo, wg=None, w=None, cnt=None, wis=None, wos=None,
+             wgs=None):
+        if wis is not None:     # serving-only int8 path: no VJP
+            return _moe_gmm_mod.moe_gmm(
+                x, wi, wo, wg, w, act=act, group_counts=cnt, wi_scale=wis,
+                wo_scale=wos, wg_scale=wgs, interpret=_interp(kb))
+        return _moe_gmm_op(act, kb, x, wi, wo, wg, w, cnt)
+
+    rows = P(*lead, None, None, None)
+    return _per_shard(call, {
+        "x": (x, rows), "wi": (wi, P(None, None, md)),
+        "wo": (wo, P(None, md, None)), "wg": (wg, P(None, None, md)),
+        "w": (weights, P(*lead, None, None)),
+        "cnt": (group_counts, P(*lead, None)),
+        "wis": (wi_scale, P(None, md)), "wos": (wo_scale, P(None, None)),
+        "wgs": (wg_scale, P(None, md))}, rows, mesh, psum_axis=md)
 
 
 def decode_attention_sharded(q, k, v, kv_pos, t, kv_valid, *, window=0,
@@ -344,42 +414,25 @@ def decode_attention_sharded(q, k, v, kv_pos, t, kv_valid, *, window=0,
     attention has no cross-head contraction, so no collective is needed —
     the output stays head-sharded and the caller's wo projection reduces it
     under GSPMD. Scale leaves (int8 caches) shard like k/v minus the Dh
-    axis. Requires Hp % model == 0 and K % model == 0 (each shard's
-    local head->kv-group mapping is then exact); anything else, or a
-    ref/trivial-mesh call, falls back to the plain entry point."""
-    from jax.sharding import PartitionSpec as P
-    from repro.runtime import sharding as SH
+    axis. Heads shard only when H % model == 0 and K % model == 0 (each
+    shard's local head->kv-group mapping is then exact)."""
     kb = resolve_backend(backend)
-    mesh, ba, d, m = _mesh_layout(mesh)
-    B, _, Hp, _ = q.shape
-    K = k.shape[2]
-    if (mesh is None or kb == "ref" or (d <= 1 and m <= 1)
-            or Hp % m or K % m or B % d):
+    lay = _shard_axes(mesh, kb, (q.shape[0],), (q.shape[2], k.shape[2]))
+    if lay is None:
         return decode_attention(q, k, v, kv_pos, t, kv_valid, kscale,
                                 vscale, window=window, backend=backend)
-    bx = ba if d > 1 else None
-    # data-only meshes still shard the batch; `model` may be absent/size-1
-    md = "model" if "model" in mesh.axis_names else None
-    quantized = kscale is not None
-
-    def body(q, k, v, kv_pos, t, kv_valid, *scales):
-        ks, vs = scales if quantized else (None, None)
-        return _decode_mod.decode_attention(q, k, v, kv_pos, t,
-                                            window=window, kv_valid=kv_valid,
-                                            kscale=ks, vscale=vs,
-                                            interpret=_interp(kb))
-
-    in_specs = (P(bx, None, md, None), P(bx, None, md, None),
-                P(bx, None, md, None), P(bx, None), P(bx),
-                P(bx, None))
-    args = (q, k, v, kv_pos, t, kv_valid)
-    if quantized:
-        in_specs += (P(bx, None, md), P(bx, None, md))
-        args += (kscale, vscale)
-    return SH.shard_map_compat(
-        body, mesh=mesh, in_specs=in_specs,
-        out_specs=P(bx, None, md, None),
-    )(*args)
+    mesh, bx, md = lay
+    heads = P(bx, None, md, None)
+    return _per_shard(
+        lambda q, k, v, pos, t, ok=None, ks=None, vs=None:
+            _decode_mod.decode_attention(
+                q, k, v, pos, t, window=window, kv_valid=ok, kscale=ks,
+                vscale=vs, interpret=_interp(kb)),
+        {"q": (q, heads), "k": (k, heads), "v": (v, heads),
+         "pos": (kv_pos, P(bx, None)), "t": (t, P(bx)),
+         "ok": (kv_valid, P(bx, None)),
+         "ks": (kscale, P(bx, None, md)), "vs": (vscale, P(bx, None, md))},
+        heads, mesh)
 
 
 def paged_decode_attention_sharded(q, kp, vp, table, t, pvalid, *,
@@ -391,27 +444,20 @@ def paged_decode_attention_sharded(q, kp, vp, table, t, pvalid, *,
     slot pages from its own replica's contiguous id range, enforced by
     ``PagePool``) is exactly pool-shard locality, so each shard gathers
     only local pages. Page-table entries arrive as GLOBAL ids and are
-    rebased in-body by the shard's page offset. Requires Hp % model == 0,
-    K % model == 0, and B/N divisible by the data size; anything else, or
-    a ref/trivial-mesh call, falls back to the plain entry point."""
-    from jax.sharding import PartitionSpec as P
-    import jax.numpy as jnp
-    from repro.runtime import sharding as SH
+    rebased in-body by the shard's page offset. Heads shard only when
+    H % model == 0 and K % model == 0; pages and slots only when the data
+    size divides both B and N."""
     kb = resolve_backend(backend)
-    mesh, ba, d, m = _mesh_layout(mesh)
-    B, _, Hp, _ = q.shape
-    N, K = kp.shape[0], kp.shape[2]
-    if (mesh is None or kb == "ref" or (d <= 1 and m <= 1)
-            or Hp % m or K % m or B % d or N % d):
+    N = kp.shape[0]
+    lay = _shard_axes(mesh, kb, (q.shape[0], N), (q.shape[2], kp.shape[2]))
+    if lay is None:
         return paged_decode_attention(q, kp, vp, table, t, pvalid, kscale,
                                       vscale, backend=backend)
-    bx = ba if d > 1 else None
-    md = "model" if "model" in mesh.axis_names else None
-    pages_per_shard = N // d
-    quantized = kscale is not None
+    mesh, bx, md = lay
+    pages_per_shard = N // (1 if bx is None else
+                            int(np.prod([mesh.shape[a] for a in bx])))
 
-    def body(q, kp, vp, table, t, pvalid, *scales):
-        ks, vs = scales if quantized else (None, None)
+    def call(q, kp, vp, table, t, pvalid, ks=None, vs=None):
         if bx is not None:
             ridx = 0
             for ax in bx:
@@ -422,98 +468,12 @@ def paged_decode_attention_sharded(q, kp, vp, table, t, pvalid, *,
             q, kp, vp, table, t, pvalid, kscale=ks, vscale=vs,
             interpret=_interp(kb))
 
-    in_specs = (P(bx, None, md, None), P(bx, None, md, None),
-                P(bx, None, md, None), P(bx, None), P(bx),
-                P(bx, None))
-    args = (q, kp, vp, table, t, pvalid)
-    if quantized:
-        # scale pools shard like the KV pool minus the Dh axis: pages over
-        # the data axes, kv-heads over `model`
-        in_specs += (P(bx, None, md), P(bx, None, md))
-        args += (kscale, vscale)
-    return SH.shard_map_compat(
-        body, mesh=mesh, in_specs=in_specs,
-        out_specs=P(bx, None, md, None),
-    )(*args)
-
-
-def fused_mlp_routed_sharded(x, idx, wi, wo, wg=None, token_weights=None,
-                             valid_count=None, *, act="swiglu", backend=None,
-                             mesh=None, wi_scale=None, wo_scale=None,
-                             wg_scale=None):
-    """Gather/scatter-fused routed MLP with the FFN dim sharded over
-    `model` (the dense-MLP TP rules: wi/wg (D, F/m), wo (F/m, D)): each
-    shard runs the index-prefetch kernel on its slice — the RoutingPlan's
-    ``idx`` rides in REPLICATED, so one plan drives every TP shard — and
-    the partial (B, S, D) deltas are psummed. On a data-only mesh (model
-    absent or size 1) the batch still shards and the psum drops out — same
-    as the decode wrapper; an unsharded fallback there would replicate the
-    (B, S, D) stream to every device. Differentiable (the inner op carries
-    the ref-replay VJP; psum transposes to its own gradient). Falls back to
-    the plain entry point off-mesh / for "ref" / when the FFN or batch dim
-    doesn't divide."""
-    from jax.sharding import PartitionSpec as P
-    from repro.runtime import sharding as SH
-    kb = resolve_backend(backend)
-    mesh, ba, d, m = _mesh_layout(mesh)
-    B = x.shape[0]
-    F = wi.shape[-1]
-    if (mesh is None or kb == "ref" or (d <= 1 and m <= 1)
-            or F % m or B % d):
-        return fused_mlp_routed(x, idx, wi, wo, wg, token_weights,
-                                valid_count, wi_scale, wo_scale, wg_scale,
-                                act=act, backend=backend)
-    bx = ba if d > 1 else None
-    md = ("model" if m > 1 and "model" in mesh.axis_names else None)
-    qw = wi_scale is not None
-    args = [x, idx, wi, wo]
-    specs = [P(bx, None, None), P(bx, None), P(None, md),
-             P(md, None)]
-    have = [True, True]             # wg / token_weights present?
-    if wg is not None:
-        args.append(wg)
-        specs.append(P(None, md))
-    else:
-        have[0] = False
-    if token_weights is not None:
-        args.append(token_weights)
-        specs.append(P(bx, None))
-    else:
-        have[1] = False
-    if valid_count is not None:
-        args.append(valid_count)
-        specs.append(P(bx) if getattr(valid_count, "ndim", 0) else P())
-    if qw:
-        # per-output-channel scales shard with their weight's output axis:
-        # wi/wg scales (F,) over `model`, wo scale (D,) replicated
-        args.append(wi_scale)
-        specs.append(P(md))
-        if have[0]:
-            args.append(wg_scale)
-            specs.append(P(md))
-        args.append(wo_scale)
-        specs.append(P(None))
-
-    def body(x, idx, wi, wo, *rest):
-        it = iter(rest)
-        wg_l = next(it) if have[0] else None
-        tw_l = next(it) if have[1] else None
-        cnt = next(it) if valid_count is not None else None
-        if qw:
-            wis = next(it)
-            wgs = next(it) if have[0] else None
-            wos = next(it)
-            # serving-only int8 path: no VJP (see fused_mlp above)
-            y = _fused_mlp_mod.fused_mlp_routed(
-                x, idx, wi, wo, wg_l, tw_l, act=act, valid_count=cnt,
-                wi_scale=wis, wo_scale=wos, wg_scale=wgs,
-                interpret=_interp(kb))
-        else:
-            y = _fused_mlp_routed_op(act, kb, x, idx, wi, wo, wg_l, tw_l,
-                                     cnt)
-        return jax.lax.psum(y, md) if md else y
-
-    return SH.shard_map_compat(
-        body, mesh=mesh, in_specs=tuple(specs),
-        out_specs=P(bx, None, None),
-    )(*args)
+    heads = P(bx, None, md, None)
+    # scale pools shard like the KV pool minus the Dh axis: pages over
+    # the data axes, kv-heads over `model`
+    return _per_shard(call, {
+        "q": (q, heads), "kp": (kp, heads), "vp": (vp, heads),
+        "table": (table, P(bx, None)), "t": (t, P(bx)),
+        "pvalid": (pvalid, P(bx, None)),
+        "ks": (kscale, P(bx, None, md)), "vs": (vscale, P(bx, None, md))},
+        heads, mesh)

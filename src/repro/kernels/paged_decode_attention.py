@@ -16,8 +16,8 @@ attendable iff
                                            skipped tokens hold no KV)
 
 The page table and per-slot lengths ride scalar prefetch and the K/V
-BlockSpec index_map gathers pages straight from the pool — the same
-index-prefetch pattern as ``fused_mlp_routed`` — with ``max(entry, 0)``
+BlockSpec index_map gathers pages straight from the pool (index
+prefetch), with ``max(entry, 0)``
 keeping unused entries in bounds (their lanes are masked). One
 (B, H, table_len) grid with the online-softmax f32 accumulator carried
 across the page dimension, GQA via the head-major index map; the jnp
@@ -34,9 +34,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128
-
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
 
 
 def analysis_example():
@@ -75,27 +72,30 @@ def _kernel(tbl_ref, t_ref, q_ref, k_ref, v_ref, pv_ref, ks_ref, vs_ref,
 
     q = q_ref[0, 0].astype(jnp.float32)                   # (1, d)
     k = k_ref[0, 0].astype(jnp.float32)                   # (ps, d)
-    if ks_ref is not None:
-        # int8 pool: widen in-register, per-(lane, kv-head) f32 scale —
-        # HBM only ever saw the int8 page (docs/quantization.md)
-        k = k * ks_ref[0, 0][:, None]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     s = s * sm_scale                                      # (1, ps)
+    if ks_ref is not None:
+        # int8 pool: each key lane's per-(lane, kv-head) f32 scale folds
+        # into its score column — HBM only ever saw the int8 page
+        # (docs/quantization.md)
+        s = s * ks_ref[0, 0]
     pos = ip * page_size + jax.lax.broadcasted_iota(
         jnp.int32, (1, page_size), 1)                     # (1, ps)
-    mask = (entry >= 0) & (pos <= t) & (pv_ref[0][None, :] > 0)
+    mask = (entry >= 0) & (pos <= t) & (pv_ref[0] > 0)
     s = jnp.where(mask, s, NEG_INF)
     m_prev = m_sc[:, 0]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
+    # masked keys get probability exactly 0 — also in a block where every
+    # key is masked (there s - m_new == 0), so a row with no attendable
+    # key keeps l == 0 and finishes as exact zeros
+    p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
     l_sc[:, 0] = l_sc[:, 0] * alpha + jnp.sum(p, axis=1)
     m_sc[:, 0] = m_new
     v = v_ref[0, 0].astype(jnp.float32)
     if vs_ref is not None:
-        v = v * vs_ref[0, 0][:, None]
-    v = jnp.where(mask[0][:, None], v, 0.0)   # masked rows: 0 * NaN guard
+        p = p * vs_ref[0, 0]       # value-row scales fold into p's columns
     acc_sc[...] = acc_sc[...] * alpha[:, None] + jax.lax.dot(
         p, v, preferred_element_type=jnp.float32)
 
@@ -133,24 +133,26 @@ def paged_decode_attention(q, kp, vp, table, t, pvalid, *, kscale=None,
     # masked in-kernel by the entry >= 0 test
     page_im = lambda b, h, p, tbl, tt: \
         (h // G, jnp.maximum(tbl[b, p], 0), 0, 0)
+    # per-lane pools ride with a unit axis before the lane axis, so every
+    # block's last two dims are (1 == full axis, ps == full axis) — the
+    # TPU tiling rule for (8, 128) blocks
     in_specs = [
         pl.BlockSpec((1, 1, 1, Dh),
                      lambda b, h, p, tbl, tt: (b, h, 0, 0)),
         pl.BlockSpec((1, 1, ps, Dh), page_im),
         pl.BlockSpec((1, 1, ps, Dh), page_im),
-        pl.BlockSpec((1, ps),
+        pl.BlockSpec((1, 1, ps),
                      lambda b, h, p, tbl, tt:
-                     (jnp.maximum(tbl[b, p], 0), 0)),
+                     (jnp.maximum(tbl[b, p], 0), 0, 0)),
     ]
-    args = [qt, kt, vt, pvalid.astype(jnp.int32)]
+    args = [qt, kt, vt, pvalid.astype(jnp.int32)[:, None, :]]
     if quantized:
-        # scale pool rides head-major like the KV pool, gathered by the
-        # same page-table index map
-        sspec = pl.BlockSpec((1, 1, ps), lambda b, h, p, tbl, tt:
-                             (h // G, jnp.maximum(tbl[b, p], 0), 0))
+        # scale pool rides head-major like the KV pool, (K, N, 1, ps),
+        # gathered by the same page-table index map
+        sspec = pl.BlockSpec((1, 1, 1, ps), page_im)
         in_specs += [sspec, sspec]
-        args += [kscale.astype(jnp.float32).transpose(2, 0, 1),
-                 vscale.astype(jnp.float32).transpose(2, 0, 1)]
+        args += [kscale.astype(jnp.float32).transpose(2, 0, 1)[:, :, None],
+                 vscale.astype(jnp.float32).transpose(2, 0, 1)[:, :, None]]
         kfn = kernel
     else:
         kfn = lambda tbl_ref, t_ref, q_ref, k_ref, v_ref, pv_ref, *rest: \
@@ -170,9 +172,10 @@ def paged_decode_attention(q, kp, vp, table, t, pvalid, *, kscale=None,
     )
     out = pl.pallas_call(
         kfn,
+        name="paged_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, Dh), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(table, t, *args)

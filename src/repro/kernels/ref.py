@@ -159,25 +159,6 @@ def fused_mlp_ref(x, wi, wo, wg=None, token_weights=None, *, act="swiglu",
     return y.astype(x.dtype)
 
 
-def fused_mlp_routed_ref(x, idx, wi, wo, wg=None, token_weights=None, *,
-                         act="swiglu", valid_count=None, wi_scale=None,
-                         wo_scale=None, wg_scale=None):
-    """Gather/compute/scatter oracle for the index-prefetch routed MLP.
-    x: (B, S, D); idx: (B, Kb); returns the (B, S, D) delta."""
-    B, S, D = x.shape
-    Kb = idx.shape[-1]
-    expand = (slice(None), slice(None), None)
-    x_sel = jnp.take_along_axis(x, idx[expand], axis=1)
-    tw = (jnp.ones((B, Kb), x.dtype) if token_weights is None
-          else token_weights)
-    y = fused_mlp_ref(x_sel, wi, wo, wg, tw, act=act,
-                      valid_count=valid_count, wi_scale=wi_scale,
-                      wo_scale=wo_scale, wg_scale=wg_scale)
-    out = jnp.zeros_like(x)
-    b = jnp.arange(B)[:, None]
-    return out.at[b, idx].add(y.astype(x.dtype))
-
-
 def moe_gmm_ref(x, wi, wo, wg=None, weights=None, *, act="swiglu",
                 group_counts=None, wi_scale=None, wo_scale=None,
                 wg_scale=None):

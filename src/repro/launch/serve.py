@@ -31,6 +31,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, get_elastic
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model_init, router_init
 from repro.runtime.elastic import make_mesh, valid_mesh_shapes
 from repro.training import GenRequest, ServingEngine
@@ -230,6 +231,7 @@ def main():
                     help="target 'data,model' shape for --remesh-at "
                          "(default: the next valid_mesh_shapes entry)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     mesh = None
     if args.mesh is not None:

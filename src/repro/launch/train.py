@@ -4,9 +4,9 @@ Wires together: config registry -> mesh -> sharded frozen base model ->
 router init -> distillation train step -> fault-tolerant supervised loop
 (checkpoint/restart, straggler watchdog) -> deterministic sharded data.
 
-On this CPU container it is exercised end-to-end with smoke configs and a
-(1,1) mesh (tests/test_train_loop.py, examples/train_elastic_lm.py); on a
-pod the same code runs under the production mesh from launch/mesh.py.
+On the CPU it is exercised end-to-end with smoke configs
+(tests/test_train_loop.py, examples/train_elastic_lm.py); ``--mesh D,M``
+runs it on a (data, model) mesh of the devices present.
 """
 from __future__ import annotations
 
@@ -23,7 +23,8 @@ from repro.configs import get_config, get_elastic
 from repro.core.policy import (as_spec_policy, capacity_anneal, ragged_bucket,
                                solve_budget)
 from repro.data import LMDataPipeline
-from repro.launch.mesh import make_production_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.serve import _mesh_shape
 from repro.models import model_init, router_init, router_param_count
 from repro.optim import cosine_schedule
 from repro.runtime import (FailureInjector, StragglerWatchdog, make_mesh,
@@ -68,7 +69,7 @@ def build_trainer(arch: str, *, variant: str = "full", mesh=None,
 def train(arch: str, *, variant: str = "smoke", total_steps: int = 100,
           seq_len: int = 128, global_batch: int = 8, lr: float = 1e-3,
           ckpt_dir: str = "/tmp/repro_ckpt", save_every: int = 25,
-          use_mesh: bool = False, multi_pod: bool = False,
+          mesh_shape: tuple = None,
           inject_failures: tuple = (), seed: int = 0,
           budget: float = None, anneal_from: float = None,
           anneal_steps: int = None):
@@ -77,7 +78,8 @@ def train(arch: str, *, variant: str = "smoke", total_steps: int = 100,
     distillation near that budget and anneal linearly to ``budget`` over
     ``anneal_steps`` (default: all steps). The policy is a *traced* argument
     of the jitted train step, so the whole schedule runs on ONE compile."""
-    mesh = make_production_mesh(multi_pod=multi_pod) if use_mesh else None
+    mesh = (make_mesh(mesh_shape, ("data", "model"))
+            if mesh_shape is not None else None)
     cfg, ecfg, params, state, step_fn, pipe = build_trainer(
         arch, variant=variant, mesh=mesh, lr=lr, total_steps=total_steps,
         seq_len=seq_len, global_batch=global_batch, seed=seed)
@@ -166,8 +168,9 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--ckpt", default="/tmp/repro_ckpt")
-    ap.add_argument("--mesh", action="store_true")
-    ap.add_argument("--multi-pod", action="store_true")
+    ap.add_argument("--mesh", type=_mesh_shape, default=None,
+                    help="train on a 'data,model' mesh of the devices "
+                         "present (e.g. 1,4)")
     ap.add_argument("--budget", type=float, default=None,
                     help="target compute budget in (0,1]; capacities from "
                          "the roofline budget solver")
@@ -176,10 +179,11 @@ def main():
                          "(traced policy: the schedule re-uses one compile)")
     ap.add_argument("--anneal-steps", type=int, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     _, metrics, restarts, _ = train(
         args.arch, variant=args.variant, total_steps=args.steps,
         seq_len=args.seq_len, global_batch=args.batch, lr=args.lr,
-        ckpt_dir=args.ckpt, use_mesh=args.mesh, multi_pod=args.multi_pod,
+        ckpt_dir=args.ckpt, mesh_shape=args.mesh,
         budget=args.budget, anneal_from=args.anneal_from,
         anneal_steps=args.anneal_steps)
     print("final:", metrics, "restarts:", restarts)

@@ -1,16 +1,14 @@
 """Grouped-query attention with RoPE, sliding windows, cross-attention,
 KV caches, and ElastiFormer hooks (head routing weights, LoRA q/v).
 
-TP formulation (§Perf H1): q-heads are zero-padded to cfg.n_heads_p (a
-multiple of the `model` mesh axis; wo pad rows are zero so the math is
-exact) and GQA is computed in *repeat-kv* form — k/v are expanded from K kv
-heads to the padded head count with a static take. Every head-indexed
-tensor then shards cleanly on one axis, so XLA partitions attention 16-way
-with no partial-sum all-reduces (the grouped (B,K,G,Sq,Sk) reshape used to
-shatter the head axis across two dims and force replication or worse).
+TP formulation (§Perf H1): the jnp twins compute GQA in *repeat-kv* form —
+k/v are expanded from K kv heads to the q-head count with a static take —
+so every head-indexed tensor shards cleanly on one axis and XLA partitions
+attention over `model` with no partial-sum all-reduces. Heads that do not
+divide the axis replicate (runtime/sharding.py).
 
 Two softmax-attention implementations:
-  * plain: materializes (B,Hp,Sq,Sk) scores — short sequences.
+  * plain: materializes (B,H,Sq,Sk) scores — short sequences.
   * blocked: lax.scan over KV chunks with online softmax (flash-style) —
     long sequences; numerically identical (f32 accumulation) and the
     jnp twin of kernels/flash_attention.py.
@@ -24,7 +22,7 @@ import jax.numpy as jnp
 
 from repro.core.lora import lora_apply
 from repro.kernels import ops as OPS
-from repro.models import flags, quant
+from repro.models import quant
 from repro.models.layers import dense_init, dtype_of, rope_apply, rope_tables
 from repro.runtime import sharding as SH
 
@@ -33,7 +31,7 @@ BLOCKED_THRESHOLD = 2048   # use blocked attention when Sk exceeds this
 KV_BLOCK = 1024
 
 
-def _kernel_ok(backend, cfg, *, window: int = 0, gathered: bool = False,
+def _kernel_ok(backend, *, window: int = 0, gathered: bool = False,
                causal: bool = True) -> bool:
     """Whether the Pallas flash/decode kernels may serve this attention
     call. The kernels mask causality/window by ARRAY INDEX (the ragged
@@ -41,59 +39,33 @@ def _kernel_ok(backend, cfg, *, window: int = 0, gathered: bool = False,
     index-causal == position-causal), but a sliding WINDOW measures
     position distance — on a gathered subset index distance underestimates
     it regardless of causality, so windowed gathered attention keeps the
-    jnp twins. TP head padding (Hp != H) would skew the kernels'
-    head->kv-group mapping."""
+    jnp twins."""
     del causal  # window masking is position-based whether causal or not
     if backend not in ("pallas", "interpret"):
-        return False
-    if cfg is not None and cfg.n_heads_p != cfg.n_heads:
         return False
     return not (window and window > 0 and gathered)
 
 
-def _expand_kv(t, hp: int, h: Optional[int] = None):
-    """(B,S,K,Dh) -> (B,S,Hp,Dh) repeat-kv (exact GQA; shards on heads).
-    h = logical head count (defaults to hp when there is no padding)."""
-    k = t.shape[2]
-    g = max(1, (h or hp) // k)
-    idx = jnp.minimum(jnp.arange(hp) // g, k - 1)
-    return jnp.take(t, idx, axis=2)
+def _expand_kv(t, h: int):
+    """(B,S,K,Dh) -> (B,S,H,Dh) repeat-kv (exact GQA; shards on heads)."""
+    return jnp.take(t, jnp.arange(h) // (h // t.shape[2]), axis=2)
 
 
 def attn_init(key, cfg, cross: bool = False):
-    D, K, Dh = cfg.d_model, cfg.n_kv_heads, cfg.d_head
-    H, Hp = cfg.n_heads, cfg.n_heads_p
+    D, H, K, Dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     dt = dtype_of(cfg)
     ks = jax.random.split(key, 4)
-
-    def pad_h(w, axis):  # zero q-head padding (exact)
-        if Hp == H:
-            return w
-        pw = [(0, 0)] * w.ndim
-        pw[axis] = (0, Hp - H)
-        return jnp.pad(w, pw)
-
     p = {
-        "wq": pad_h(dense_init(ks[0], D, H * Dh, dt).reshape(D, H, Dh), 1),
+        "wq": dense_init(ks[0], D, H * Dh, dt).reshape(D, H, Dh),
         "wk": dense_init(ks[1], D, K * Dh, dt).reshape(D, K, Dh),
         "wv": dense_init(ks[2], D, K * Dh, dt).reshape(D, K, Dh),
-        "wo": pad_h(dense_init(ks[3], H * Dh, D, dt).reshape(H, Dh, D), 0),
+        "wo": dense_init(ks[3], H * Dh, D, dt).reshape(H, Dh, D),
     }
     if cfg.qkv_bias:
-        p["bq"] = jnp.zeros((Hp, Dh), dt)
+        p["bq"] = jnp.zeros((H, Dh), dt)
         p["bk"] = jnp.zeros((K, Dh), dt)
         p["bv"] = jnp.zeros((K, Dh), dt)
     return p
-
-
-def _pad_heads(t, cfg, axis: int = -1, fill: float = 0.0):
-    """Pad a head-indexed tensor on `axis` from H to Hp."""
-    H, Hp = cfg.n_heads, cfg.n_heads_p
-    if Hp == H:
-        return t
-    pw = [(0, 0)] * t.ndim
-    pw[axis] = (0, Hp - H)
-    return jnp.pad(t, pw, constant_values=fill)
 
 
 def _lora_scale(lora, d):
@@ -108,14 +80,14 @@ def _project_q(p, x, positions, cfg, lora, use_rope):
     # maybe_dequant: identity for fp32/bf16 trees, int8 * scale otherwise
     q = jnp.einsum("bsd,dhk->bshk", x,
                    quant.maybe_dequant(p, "wq", x.dtype))
-    # (B,S,Hp,Dh)
+    # (B,S,H,Dh)
     if lora is not None and "q" in lora:
         H, Dh = cfg.n_heads, cfg.d_head
         dq = lora_apply(lora["q"], x).reshape(x.shape[0], x.shape[1], H, Dh)
         s = _lora_scale(lora, dq.ndim)
         if s is not None:
             dq = dq * s.astype(dq.dtype)
-        q = q + _pad_heads(dq, cfg, axis=2)
+        q = q + dq
     if "bq" in p:
         q = q + p["bq"]
     if use_rope:
@@ -162,15 +134,14 @@ def _mask(q_pos, kv_pos, causal: bool, window: int, kv_valid=None):
     return m
 
 
-def sdpa(q, k, v, mask, cfg=None):
-    """q:(B,Sq,Hp,Dh) k,v:(B,Sk,K,Dh) mask:(B?,Sq,Sk) -> (B,Sq,Hp,Dh).
+def sdpa(q, k, v, mask):
+    """q:(B,Sq,H,Dh) k,v:(B,Sk,K,Dh) mask:(B?,Sq,Sk) -> (B,Sq,H,Dh).
 
     Repeat-kv GQA (head axis shards whole); f32 softmax."""
-    B, Sq, Hp, Dh = q.shape
+    B, Sq, H, Dh = q.shape
     mqa = k.shape[2] == 1  # MQA: broadcast kv in the einsum, never expand
-    if k.shape[2] != Hp and not mqa:
-        h = cfg.n_heads if cfg is not None else Hp
-        k, v = _expand_kv(k, Hp, h), _expand_kv(v, Hp, h)
+    if k.shape[2] != H and not mqa:
+        k, v = _expand_kv(k, H), _expand_kv(v, H)
     scale = Dh ** -0.5
     if mqa:
         s = jnp.einsum("bqhd,bsd->bhqs", q, k[:, :, 0])
@@ -189,20 +160,15 @@ def sdpa(q, k, v, mask, cfg=None):
 
 
 def blocked_sdpa(q, k, v, q_pos, kv_pos, causal, window, kv_valid=None,
-                 block: int = KV_BLOCK, cfg=None):
+                 block: int = KV_BLOCK):
     """Flash-style online-softmax attention, lax.scan over KV blocks.
 
     Identical math to sdpa (f32 accumulators), O(Sq*block) live memory."""
-    if flags.unroll():
-        # analysis mode: cap trip count at 64 so full unroll stays compilable
-        block = max(block, -(-k.shape[1] // 64))
-        block = -(-block // 128) * 128
-    B, Sq, Hp, Dh = q.shape
+    B, Sq, H, Dh = q.shape
     Sk = k.shape[1]
     mqa = k.shape[2] == 1  # MQA: broadcast kv in the einsums, never expand
-    if k.shape[2] != Hp and not mqa:
-        h = cfg.n_heads if cfg is not None else Hp
-        k, v = _expand_kv(k, Hp, h), _expand_kv(v, Hp, h)
+    if k.shape[2] != H and not mqa:
+        k, v = _expand_kv(k, H), _expand_kv(v, H)
     kvh = k.shape[2]
     nb = -(-Sk // block)
     pad = nb * block - Sk
@@ -251,11 +217,10 @@ def blocked_sdpa(q, k, v, q_pos, kv_pos, causal, window, kv_valid=None,
         acc = acc * alpha[..., None] + pv
         return (m_new, l_new, acc), None
 
-    m0 = jnp.full((B, Hp, Sq), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((B, Hp, Sq), jnp.float32)
-    a0 = jnp.zeros((B, Hp, Sq, Dh), jnp.float32)
-    (m_f, l_f, acc), _ = jax.lax.scan(body, (m0, l0, a0), (kb, vb, pb, mb),
-                                      unroll=flags.unroll())
+    m0 = jnp.full((B, H, Sq), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((B, H, Sq), jnp.float32)
+    a0 = jnp.zeros((B, H, Sq, Dh), jnp.float32)
+    (m_f, l_f, acc), _ = jax.lax.scan(body, (m0, l0, a0), (kb, vb, pb, mb))
     out = acc / jnp.maximum(l_f, 1e-30)[..., None]
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
@@ -288,25 +253,26 @@ def attn_apply(
     else:
         k, v = _project_kv(p, x, positions, cfg, lora, use_rope)
         kvp = positions
-    if _kernel_ok(backend, cfg, window=window, gathered=gathered,
+    if _kernel_ok(backend, window=window, gathered=gathered,
                   causal=causal and not cross):
         if kv_valid is not None and kv_valid.ndim == 1:
             kv_valid = jnp.broadcast_to(kv_valid, k.shape[:2])
-        ctx = OPS.flash_attention(q, k, v, kv_valid=kv_valid,
-                                  kv_count=kv_count,
-                                  causal=causal and not cross,
-                                  window=window or 0, backend=backend)
+        ctx = OPS.flash_attention_sharded(q, k, v, kv_valid=kv_valid,
+                                          kv_count=kv_count,
+                                          causal=causal and not cross,
+                                          window=window or 0,
+                                          backend=backend)
     else:
         eff_window = window if (window and window > 0) else k.shape[1]
         if min(k.shape[1], eff_window) > BLOCKED_THRESHOLD:
             qp = positions if positions.ndim == 2 else jnp.broadcast_to(positions, x.shape[:2])
             ctx = blocked_sdpa(q, k, v, qp, kvp, causal and not cross, window,
-                               kv_valid, cfg=cfg)
+                               kv_valid)
         else:
             mask = _mask(positions, kvp, causal and not cross, window, kv_valid)
-            ctx = sdpa(q, k, v, mask, cfg=cfg)
+            ctx = sdpa(q, k, v, mask)
     if head_weights is not None:
-        ctx = ctx * _pad_heads(head_weights, cfg)[..., None].astype(ctx.dtype)
+        ctx = ctx * head_weights[..., None].astype(ctx.dtype)
     out = jnp.einsum("bshk,hkd->bsd", ctx,
                      quant.maybe_dequant(p, "wo", ctx.dtype))
     return out, k, v
@@ -393,7 +359,7 @@ def attn_decode(
     if quantized:
         new_cache["kscale"], new_cache["vscale"] = cks, cvs
     kv_valid = valid & (cpos >= 0)
-    if _kernel_ok(backend, cfg):
+    if _kernel_ok(backend):
         # ring-cache decode kernel: per-slot positions ride scalar
         # prefetch, masking is by the cache's absolute-position array.
         # Under a mesh the kernel runs per-shard (heads over `model`,
@@ -409,12 +375,12 @@ def attn_decode(
         cvf = quant.dequantize_kv(cv, cvs, q.dtype) if quantized else cv
         if L > BLOCKED_THRESHOLD:
             ctx = blocked_sdpa(q, ckf, cvf, pos, cpos, True, window,
-                               kv_valid, cfg=cfg)
+                               kv_valid)
         else:
             mask = _mask(pos, cpos, True, window, kv_valid)
-            ctx = sdpa(q, ckf, cvf, mask, cfg=cfg)
+            ctx = sdpa(q, ckf, cvf, mask)
     if head_weights is not None:
-        ctx = ctx * _pad_heads(head_weights, cfg)[..., None].astype(ctx.dtype)
+        ctx = ctx * head_weights[..., None].astype(ctx.dtype)
     out = jnp.einsum("bshk,hkd->bsd", ctx,
                      quant.maybe_dequant(p, "wo", ctx.dtype))
     return out, new_cache
@@ -543,7 +509,7 @@ def attn_decode_paged(
             return SH.constrain_page_pool(c.at[pages, offs].set(new), cfg)
         new_cache["kscale"] = upds(cache["kscale"], ks_new)
         new_cache["vscale"] = upds(cache["vscale"], vs_new)
-    if _kernel_ok(backend, cfg):
+    if _kernel_ok(backend):
         # paged decode kernel: the table and per-slot lengths ride scalar
         # prefetch, the BlockSpec index_map gathers pages from the pool.
         # Under a mesh it runs per-shard (kv-heads over `model`, pages and
@@ -556,11 +522,11 @@ def attn_decode_paged(
         kg, vg, kvv, kvpos = _paged_gather(new_cache, table, B,
                                            dtype=q.dtype)
         mask = _mask(pos, kvpos[None], True, 0, kvv)
-        ctx = sdpa(q, kg, vg, mask, cfg=cfg)
+        ctx = sdpa(q, kg, vg, mask)
         # rows with no attendable key: match the kernel's exact zeros
         ctx = jnp.where(mask.any(-1)[:, :, None, None], ctx, 0.0)
     if head_weights is not None:
-        ctx = ctx * _pad_heads(head_weights, cfg)[..., None].astype(ctx.dtype)
+        ctx = ctx * head_weights[..., None].astype(ctx.dtype)
     out = jnp.einsum("bshk,hkd->bsd", ctx,
                      quant.maybe_dequant(p, "wo", ctx.dtype))
     return out, new_cache
@@ -615,9 +581,9 @@ def attn_chunk(
     kg, vg, kvv, kvpos = _paged_gather(new_cache, table_row[None], B,
                                        dtype=q.dtype)
     mask = _mask(positions, kvpos[None], True, 0, kvv)
-    ctx = sdpa(q, kg, vg, mask, cfg=cfg)
+    ctx = sdpa(q, kg, vg, mask)
     if head_weights is not None:
-        ctx = ctx * _pad_heads(head_weights, cfg)[..., None].astype(ctx.dtype)
+        ctx = ctx * head_weights[..., None].astype(ctx.dtype)
     out = jnp.einsum("bshk,hkd->bsd", ctx,
                      quant.maybe_dequant(p, "wo", ctx.dtype))
     return out, new_cache

@@ -35,7 +35,7 @@ attention and MLP/MoE students; each weights the shared token set with its
 own router), full-budget policies compile the identity graph (no routing
 work, bit-exact teacher), and ``spec.kernel_backend`` dispatches the block
 math through the Pallas kernels (flash attention with scalar-prefetched
-kv_count, fused/routed MLP, grouped expert matmul, ring-cache decode
+kv_count, fused MLP, grouped expert matmul, ring-cache decode
 attention) or their jnp twins.
 """
 from __future__ import annotations
@@ -56,13 +56,6 @@ from repro.models import rglru as G
 from repro.models import ssm as S
 from repro.models.layers import mlp_apply, mlp_init, norm_apply, norm_init
 from repro.models.moe import moe_apply, moe_decode, moe_init
-
-
-# VMEM budget for fused_mlp_routed's resident per-row output slab (it
-# holds one (S, D) block for a whole batch row): ~4 MiB leaves room for
-# the weight/f-tiles on a 16 MiB-VMEM core. Beyond it the plan path falls
-# back to gather-in-XLA + the batched fused_mlp kernel.
-ROUTED_MLP_SLAB_BYTES = 4 * 1024 * 1024
 
 
 def has_mlp(kind: str) -> bool:
@@ -219,24 +212,12 @@ def _mlp_fn(p, rp, cfg, spec, pol, elastic_on, mode, auxes, backend=None):
             return y
         if backend in ("pallas", "interpret"):
             mp = p["mlp"]
-            return OPS.fused_mlp(h, mp["wi"], mp["wo"], mp.get("wg"),
-                                 valid_count=token_count,
-                                 wi_scale=mp.get("wi_scale"),
-                                 wo_scale=mp.get("wo_scale"),
-                                 wg_scale=mp.get("wg_scale"), act=cfg.act,
-                                 backend=backend)
+            return OPS.fused_mlp_sharded(
+                h, mp["wi"], mp["wo"], mp.get("wg"), valid_count=token_count,
+                wi_scale=mp.get("wi_scale"), wo_scale=mp.get("wo_scale"),
+                wg_scale=mp.get("wg_scale"), act=cfg.act, backend=backend)
         return mlp_apply(p["mlp"], h, cfg.act)
     return f
-
-
-def _is_dense_mlp(p, rp, cfg, spec, elastic_on, mode) -> bool:
-    """True when the MLP sub-block is the plain dense MLP (no native MoE,
-    no moefied expert routing) — the case the gather/scatter-fused routed
-    kernel (``fused_mlp_routed``) can serve directly."""
-    if cfg.moe is not None:
-        return False
-    return not (elastic_on and rp and "expert" in rp and mode != "base"
-                and spec is not None and spec.mlp_n_experts)
 
 
 # --------------------- full-sequence block apply ----------------------------
@@ -303,10 +284,11 @@ def block_apply(
     kernels/ops.py).
 
     ``spmd_auto``: True when this trace runs in a GSPMD-auto region (no
-    enclosing manual shard_map), where mesh-wide sharding constraints and
-    nested shard_map kernel wrappers are legal — the serving prefill path.
-    ``_run_stack`` sets it False inside its manual-over-batch-axes wrap."""
-    B, Seq, D = x.shape
+    enclosing manual shard_map), where mesh-wide sharding constraints are
+    legal — the serving prefill path. ``_run_stack`` sets it False inside
+    its manual-over-batch-axes wrap. (The kernel wrappers in
+    kernels/ops.py detect that region themselves.)"""
+    B, Seq = x.shape[:2]
     auxes = [R.RouteAux.zero()]
     if positions is None:
         positions = jnp.arange(Seq, dtype=jnp.int32)
@@ -588,36 +570,13 @@ def block_apply(
                     w_sel = plan.valid.astype(jnp.float32)
                 if depth_w_sel is not None:
                     w_sel = w_sel * depth_w_sel
-            # the gather/scatter-fused kernel keeps one (S, D) output slab
-            # resident in VMEM — only profitable (and compilable) while
-            # that slab fits; bigger shapes gather in XLA and run the
-            # batched fused_mlp kernel on the bucket buffer instead
-            slab = Seq * D * jnp.dtype(x.dtype).itemsize
-            if (backend in ("pallas", "interpret")
-                    and _is_dense_mlp(p, rp, cfg, spec, elastic_on, mode)
-                    and slab <= ROUTED_MLP_SLAB_BYTES):
-                # plan indices ride scalar prefetch; the bucket buffer
-                # never hits HBM. Under a mesh (GSPMD-auto region) the
-                # kernel runs per-shard over the FFN dim via shard_map —
-                # ops.fused_mlp_routed_sharded falls through to the plain
-                # call off-mesh or when shapes don't divide.
-                routed_op = (OPS.fused_mlp_routed_sharded if spmd_auto
-                             else OPS.fused_mlp_routed)
-                delta = routed_op(
-                    h, plan.idx, p["mlp"]["wi"], p["mlp"]["wo"],
-                    p["mlp"].get("wg"), w_sel, valid_count=plan.count,
-                    wi_scale=p["mlp"].get("wi_scale"),
-                    wo_scale=p["mlp"].get("wo_scale"),
-                    wg_scale=p["mlp"].get("wg_scale"),
-                    act=cfg.act, backend=backend).astype(x.dtype)
-            else:
-                h_sel = R.plan_gather(h, plan)
-                pos_sel = jnp.take_along_axis(
-                    jnp.broadcast_to(positions, (B, Seq)), plan.idx, 1)
-                y_sel = f(h_sel, pos_sel, token_valid=plan.valid,
-                          token_count=plan.count)
-                delta = R.plan_scatter(
-                    plan, x, y_sel * w_sel[..., None].astype(y_sel.dtype))
+            h_sel = R.plan_gather(h, plan)
+            pos_sel = jnp.take_along_axis(
+                jnp.broadcast_to(positions, (B, Seq)), plan.idx, 1)
+            y_sel = f(h_sel, pos_sel, token_valid=plan.valid,
+                      token_count=plan.count)
+            delta = R.plan_scatter(
+                plan, x, y_sel * w_sel[..., None].astype(y_sel.dtype))
         elif mode == "train":
             # dense fallback (traced capacity without a covering bucket, or
             # dense_mask impl): selection shared with the mixer stage when

@@ -36,7 +36,6 @@ from repro.models.blocks import (block_apply, block_cache_init, block_chunk,
                                  block_router_init, block_init,
                                  cache_row_insert)
 from repro.models.layers import dense_init, dtype_of, norm_apply, norm_init
-from repro.models import flags
 
 
 class PatternPos(NamedTuple):
@@ -268,7 +267,7 @@ def _run_stack(params, rparams, x, *, cfg, spec, pol, mode, period, causal,
             y, a = f(lp, lrp, lpol, xx, ekv, evd)
             return y, jax.tree.map(lambda s: jax.lax.pmean(s, ba), a)
 
-        return _SH.shard_map_compat(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(), pol_specs, P(ba, None, None),
                       P() if enc_kv is None else P(ba, None, None),
@@ -309,8 +308,7 @@ def _run_stack(params, rparams, x, *, cfg, spec, pol, mode, period, causal,
             xs["r"] = rparams["scan"]
         if layered:
             xs["pol"] = pol_scan
-        (x, aux), _ = jax.lax.scan(body, (x, aux0), xs,
-                                    unroll=flags.unroll())
+        (x, aux), _ = jax.lax.scan(body, (x, aux0), xs)
     else:
         aux = aux0
     for i, (lp, _ent, lrp, lpol) in enumerate(_tail_plan(
@@ -457,7 +455,7 @@ def prefill(params, rparams, batch, cfg, ecfg=None, mode: str = "infer",
             xs["r"] = rparams["scan"]
         if layered:
             xs["pol"] = pol_scan
-        x, scan_caches = jax.lax.scan(body, x, xs, unroll=flags.unroll())
+        x, scan_caches = jax.lax.scan(body, x, xs)
     else:
         scan_caches = []
     tail_caches = []
@@ -557,7 +555,7 @@ def decode_step(params, rparams, token, caches, t, cfg, ecfg=None,
             xs["r"] = rparams["scan"]
         if layered:
             xs["pol"] = pol_scan
-        x, new_scan = jax.lax.scan(body, x, xs, unroll=flags.unroll())
+        x, new_scan = jax.lax.scan(body, x, xs)
     else:
         new_scan = []
     new_tail = []
@@ -635,7 +633,7 @@ def prefill_chunk_step(params, rparams, tokens, caches, write_page, table_row,
             xs["r"] = rparams["scan"]
         if layered:
             xs["pol"] = pol_scan
-        x, new_scan = jax.lax.scan(body, x, xs, unroll=flags.unroll())
+        x, new_scan = jax.lax.scan(body, x, xs)
     else:
         new_scan = []
     new_tail = []

@@ -19,7 +19,7 @@ from repro.core.routing import RouteAux, bcast_to, is_full, topk_mask, \
     topk_mask_dyn
 from repro.kernels import ops as OPS
 from repro.models.layers import act_fn, dense_init, dtype_of, is_gated
-from repro.models import flags, quant
+from repro.models import quant
 
 
 def moe_init(key, cfg):
@@ -53,12 +53,12 @@ def _expert_ffn(p, x_sel, act, backend=None, counts=None):
     the dispatch gather keeps the valid slots a per-(b,e) prefix, so the
     counts are exact, not a bound."""
     if backend in ("pallas", "interpret"):
-        return OPS.moe_gmm(x_sel, p["wi"], p["wo"], p.get("wg"),
-                           group_counts=counts,
-                           wi_scale=p.get("wi_scale"),
-                           wo_scale=p.get("wo_scale"),
-                           wg_scale=p.get("wg_scale"),
-                           act=act, backend=backend)
+        return OPS.moe_gmm_sharded(x_sel, p["wi"], p["wo"], p.get("wg"),
+                                   group_counts=counts,
+                                   wi_scale=p.get("wi_scale"),
+                                   wo_scale=p.get("wo_scale"),
+                                   wg_scale=p.get("wg_scale"),
+                                   act=act, backend=backend)
     h = jnp.einsum("becd,edf->becf", x_sel,
                    quant.maybe_dequant(p, "wi", x_sel.dtype))
     if "wg" in p:
@@ -195,12 +195,11 @@ def moe_apply(
     if tv is not None:
         tvs = tv.reshape(B, n_chunks, chunk).transpose(1, 0, 2)
         ys, loads = jax.lax.scan(
-            lambda c, xv: (c, one_chunk(*xv)), None, (xs, vs, tvs),
-            unroll=flags.unroll())[1]
+            lambda c, xv: (c, one_chunk(*xv)), None, (xs, vs, tvs))[1]
     else:
         ys, loads = jax.lax.scan(
-            lambda c, xv: (c, one_chunk(xv[0], xv[1], None)), None, (xs, vs),
-            unroll=flags.unroll())[1]
+            lambda c, xv: (c, one_chunk(xv[0], xv[1], None)), None,
+            (xs, vs))[1]
     y = ys.transpose(1, 0, 2, 3).reshape(B, s_pad, D)[:, :S]
     if "shared" in p:
         y = y + _dense_ffn(p["shared"], x_orig, act)

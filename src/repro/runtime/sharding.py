@@ -2,9 +2,8 @@
 DP over (`pod`,`data`)).
 
 Rules are name+rank based over the parameter pytree, so one table covers all
-ten architectures. Uneven head counts (phi3 40H, qwen2 28H, recurrentgemma
-10H over a 16-way model axis) rely on GSPMD implicit padding — documented in
-DESIGN.md §4.
+ten architectures. A dim that does not divide its mesh axis is replicated
+(``_fit_spec``) — documented in DESIGN.md §4.
 
 KV caches shard kv-heads over `model` when divisible, else fall back to
 sharding head_dim (always 128 | 64) — the fallback's extra collectives are a
@@ -35,31 +34,6 @@ def data_axis_size(mesh: Optional[Mesh]) -> int:
     for a in batch_axes(mesh):
         n *= mesh.shape.get(a, 1)
     return n
-
-
-def shard_map_compat(body, *, mesh, in_specs, out_specs, axis_names=None,
-                     check_vma=False):
-    """Version-compatible shard_map: newer JAX exposes ``jax.shard_map``
-    (axis_names/check_vma kwargs); 0.4.x has only
-    ``jax.experimental.shard_map.shard_map`` (check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=check_vma)
-
-
-def abstract_mesh(shape, axes):
-    """Version-compatible ``jax.sharding.AbstractMesh`` constructor: newer
-    JAX takes ``(axis_sizes, axis_names)``, older releases a single
-    ``((name, size), ...)`` shape tuple. Lets tests exercise production
-    (16, 16) axis sizes without 256 devices."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
 
 
 def active_mesh() -> Optional[Mesh]:

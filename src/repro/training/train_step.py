@@ -25,7 +25,6 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.distill import (cosine_distance, distill_loss,
                                 topk_kl_from_gathered)
@@ -142,10 +141,10 @@ def chunked_topk_kl(h_student, h_teacher, head, *, k: int, vocab: int,
         out = jnp.mean(kls) * temp * temp
         return jax.lax.pmean(out, ba) if ba else out
 
-    f = shard_map(
+    f = jax.shard_map(
         sharded, mesh=mesh,
         in_specs=(P(ba, None, None), P(ba, None, None), P(None, "model")),
-        out_specs=P(), check_rep=False)
+        out_specs=P(), check_vma=False)
     return f(h_student, h_teacher, head)
 
 
@@ -253,9 +252,7 @@ def make_train_step(cfg, ecfg, *, lr, weight_decay: float = 0.0,
         m0 = {k: jnp.zeros((), jnp.float32)
               for k in ("loss", "distill", "aux_load", "aux_topk",
                         "sel_rate")}
-        from repro.models import flags as _flags
-        (g, m), _ = jax.lax.scan(body, (g0, m0), jnp.arange(microbatch),
-                                 unroll=_flags.unroll())
+        (g, m), _ = jax.lax.scan(body, (g0, m0), jnp.arange(microbatch))
         inv = 1.0 / microbatch
         return (jax.tree.map(lambda x: x * inv, g),
                 {k: v * inv for k, v in m.items()})

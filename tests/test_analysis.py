@@ -255,6 +255,14 @@ def test_pallas_flags_misaligned_tile():
     finds = pallas_lint.verify_record(
         "k", _rec((2,), (100,), (200,), lambda i: (i,)))
     assert "PAL-ALIGN" in _rules(finds)
+    # a one-row block of a many-row array is refused by the TPU lowering
+    # (it is neither a multiple of 8 nor the full axis) ...
+    finds = pallas_lint.verify_record(
+        "k", _rec((8,), (1, 128), (8, 128), lambda i: (i, 0)))
+    assert "PAL-ALIGN" in _rules(finds)
+    # ... while the same row through a unit axis is the full (1, 128) tail
+    assert pallas_lint.verify_record(
+        "k", _rec((8,), (1, 1, 128), (8, 1, 128), lambda i: (i, 0, 0))) == []
 
 
 def test_pallas_flags_unprefetched_control_vector():

@@ -8,7 +8,7 @@ import pytest
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.fused_mlp import fused_mlp, fused_mlp_routed
+from repro.kernels.fused_mlp import fused_mlp
 from repro.kernels.moe_gmm import moe_gmm
 
 TOLS = {jnp.float32: dict(atol=2e-5, rtol=2e-5),
@@ -44,6 +44,7 @@ def test_flash_attention_sweep(B, Sq, Sk, H, K, Dh, causal, window, dtype, key):
     (256, 128, 512, True, "swiglu"),
     (100, 128, 384, True, "geglu"),      # ragged T
     (512, 256, 1024, False, "gelu"),
+    (64, 128, 320, True, "swiglu"),      # partial last F tile (256 + 64)
 ])
 def test_fused_mlp_sweep(T, D, F, gated, act, dtype, key):
     ks = jax.random.split(key, 5)
@@ -63,6 +64,7 @@ def test_fused_mlp_sweep(T, D, F, gated, act, dtype, key):
     (4, 128, 128, 256, True),
     (8, 96, 64, 128, False),     # ragged C
     (2, 256, 128, 512, True),
+    (2, 64, 128, 296, True),     # partial last Fe tile (256 + 40)
 ])
 def test_moe_gmm_sweep(E, C, D, Fe, gated, dtype, key):
     ks = jax.random.split(key, 5)
@@ -172,33 +174,31 @@ def test_fused_mlp_batched_per_row_counts(key):
 
 
 @pytest.mark.parametrize("gated", [True, False])
-def test_fused_mlp_routed_gather_scatter_fusion(gated, key):
-    """Index-prefetch gather/scatter fusion: x stays full (B,S,D), the
-    plan indices ride scalar prefetch, the output is the scattered delta —
-    rows the plan dropped stay exactly zero."""
-    B, S, Kb, D, F = 2, 96, 24, 64, 128
-    ks = jax.random.split(key, 6)
+def test_plan_gather_fused_mlp_scatter(gated, key):
+    """The routed MLP as the model runs it: a RoutingPlan gathers the
+    selected rows into a bucket buffer, fused_mlp runs on its valid
+    prefix (per-row counts), and the weighted outputs scatter back to
+    their token positions; rows the plan dropped stay exactly zero."""
+    from repro.core import routing as R
+    B, S, D, F = 2, 96, 64, 128
+    ks = jax.random.split(key, 5)
     x = jax.random.normal(ks[0], (B, S, D))
     wi = jax.random.normal(ks[1], (D, F)) * 0.05
     wo = jax.random.normal(ks[2], (F, D)) * 0.05
     wg = (jax.random.normal(ks[3], (D, F)) * 0.05) if gated else None
-    idx = jnp.stack([jax.random.permutation(
-        jax.random.fold_in(ks[4], b), S)[:Kb] for b in range(B)])
-    idx = jnp.sort(idx, axis=-1).astype(jnp.int32)
-    cnt = jnp.asarray([Kb, 10], jnp.int32)
-    tw = jax.random.uniform(ks[5], (B, Kb)) \
-        * (jnp.arange(Kb)[None] < cnt[:, None])
-    got = fused_mlp_routed(x, idx, wi, wo, wg, tw, act="swiglu",
-                           valid_count=cnt, interpret=True)
-    want = ref.fused_mlp_routed_ref(x, idx, wi, wo, wg, tw, act="swiglu",
-                                    valid_count=cnt)
+    scores = jax.random.uniform(ks[4], (B, S))
+    plan = R.make_plan(scores, jnp.asarray([24, 10], jnp.int32), 32)
+    w = jnp.take_along_axis(scores, plan.idx, 1) * plan.valid
+    y = fused_mlp(R.plan_gather(x, plan), wi, wo, wg, act="swiglu",
+                  valid_count=plan.count, interpret=True)
+    got = R.plan_scatter(plan, x, y * w[..., None])
+    want = jnp.where(plan.keep[..., None],
+                     ref.fused_mlp_ref(x, wi, wo, wg, scores, act="swiglu"),
+                     0.0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
-    # untouched rows are exact zeros
-    touched = np.zeros((B, S), bool)
-    for b in range(B):
-        touched[b, np.asarray(idx[b, :int(cnt[b])])] = True
-    assert not np.asarray(got)[~touched].any()
+    assert not np.asarray(got)[~np.asarray(plan.keep)].any()
+    assert np.asarray(plan.keep).sum(-1).tolist() == [24, 10]
 
 
 def test_moe_gmm_batched_group_counts(key):

@@ -99,3 +99,22 @@ def test_entry_points_donate_and_stay_compile_flat(key):
     outs = engine.generate(reqs)
     assert all(len(o) == 4 for o in outs)
     assert engine.compile_counts() == {"prefill": 1, "decode": 1}
+
+
+def test_compile_cache_dir_is_fixed_or_from_env(monkeypatch):
+    """The launchers' persistent compilation cache: JAX_COMPILATION_CACHE_DIR
+    when set (JAX reads it; nothing else is set in code), else a fixed
+    directory inside the checkout — never a temp name, a pid or the time."""
+    from repro.launch.compile_cache import CHECKOUT, enable_compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = enable_compile_cache()
+        assert path == str(CHECKOUT / ".jax_cache") == enable_compile_cache()
+        assert (CHECKOUT / "chip_smoke.py").is_file()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

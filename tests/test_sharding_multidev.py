@@ -12,8 +12,8 @@ import pytest
 
 def _run_spmd_script(script: str):
     env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.abspath(
-        os.path.join(os.path.dirname(__file__), "..", "src"))
+    root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+    env["PYTHONPATH"] = os.pathsep.join([os.path.join(root, "src"), root])
     r = subprocess.run([sys.executable, "-c", script], env=env,
                        capture_output=True, text=True, timeout=900)
     assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-3000:]}"
@@ -217,30 +217,52 @@ np.testing.assert_allclose(
                                          kv_valid=valid)),
     rtol=1e-5, atol=1e-5)
 
-S, D, F, Kb = 16, 8, 32, 8
+S, D, F = 16, 8, 32
 x = jax.random.normal(key, (B, S, D), jnp.float32)
 wi = jax.random.normal(jax.random.fold_in(key, 4), (D, F), jnp.float32) * .1
 wo = jax.random.normal(jax.random.fold_in(key, 5), (F, D), jnp.float32) * .1
 wg = jax.random.normal(jax.random.fold_in(key, 6), (D, F), jnp.float32) * .1
-idx = jnp.tile(jnp.arange(Kb, dtype=jnp.int32)[None], (B, 1))
-tw = jnp.ones((B, Kb), jnp.float32)
-cnt = jnp.asarray([8, 5, 8, 3], jnp.int32)
-seen2 = []
-orig2 = _fm.fused_mlp_routed
-def probe2(x, idx, wi, *a, **kw):
-    seen2.append((x.shape, idx.shape, wi.shape))
-    return orig2(x, idx, wi, *a, **kw)
-_fm.fused_mlp_routed = probe2
-with mesh:
-    got = jax.jit(lambda *a: OPS.fused_mlp_routed_sharded(
-        *a, act="swiglu", backend="interpret"))(x, idx, wi, wo, wg, tw, cnt)
-_fm.fused_mlp_routed = orig2
-# FFN dim sharded over model, plan idx replicated into every shard
-assert ((B // 2, S, D), (B // 2, Kb), (D, F // 4)) in seen2, seen2
+
+# prefill flash attention, the dense fused MLP and the grouped expert
+# matmul: Mosaic refuses to auto-partition a kernel, so under a mesh each
+# runs per shard too (heads / the FFN dim over `model`, batch over data)
+def per_shard(mod, name, wrapper, args, kw, want):
+    seen, orig = [], getattr(mod, name)
+    def probe(*a, **k):
+        seen.append(tuple(getattr(v, "shape", None) for v in a[:3]))
+        return orig(*a, **k)
+    setattr(mod, name, probe)
+    with mesh:
+        got = jax.jit(lambda *a: wrapper(*a, **kw))(*args)
+    setattr(mod, name, orig)
+    assert want in seen, seen
+    return got
+
+qf = jax.random.normal(key, (B, S, H, Dh), jnp.float32)
+kf = jax.random.normal(jax.random.fold_in(key, 7), (B, S, K, Dh))
+vf = jax.random.normal(jax.random.fold_in(key, 8), (B, S, K, Dh))
+got = per_shard(OPS._flash_mod, "flash_attention", OPS.flash_attention_sharded,
+                (qf, kf, vf), dict(causal=True, backend="interpret"),
+                ((B // 2, S, H // 4, Dh), (B // 2, S, K // 4, Dh),
+                 (B // 2, S, K // 4, Dh)))
 np.testing.assert_allclose(
-    np.asarray(got),
-    np.asarray(KREF.fused_mlp_routed_ref(x, idx, wi, wo, wg, tw,
-                                         act="swiglu", valid_count=cnt)),
+    np.asarray(got), np.asarray(KREF.flash_attention_ref(qf, kf, vf)),
+    rtol=1e-5, atol=1e-5)
+got = per_shard(_fm, "fused_mlp", OPS.fused_mlp_sharded,
+                (x, wi, wo, wg), dict(backend="interpret"),
+                ((B // 2, S, D), (D, F // 4), (F // 4, D)))
+np.testing.assert_allclose(
+    np.asarray(got), np.asarray(KREF.fused_mlp_ref(x, wi, wo, wg)),
+    rtol=1e-4, atol=1e-5)
+E, C = 2, 8
+xe = jax.random.normal(key, (B, E, C, D), jnp.float32)
+wie = jax.random.normal(jax.random.fold_in(key, 9), (E, D, F)) * .1
+woe = jax.random.normal(jax.random.fold_in(key, 10), (E, F, D)) * .1
+got = per_shard(OPS._moe_gmm_mod, "moe_gmm", OPS.moe_gmm_sharded,
+                (xe, wie, woe), dict(act="gelu", backend="interpret"),
+                ((B // 2, E, C, D), (E, D, F // 4), (E, F // 4, D)))
+np.testing.assert_allclose(
+    np.asarray(got), np.asarray(KREF.moe_gmm_ref(xe, wie, woe, act="gelu")),
     rtol=1e-4, atol=1e-5)
 print("KERNEL-SHARD-OK")
 """
@@ -391,8 +413,11 @@ while not all(h.done for h in handles):
 assert eng.compile_counts() == {"prefill": 1, "decode": 1}, \
     eng.compile_counts()
 assert {eng.scheduler.replica_of(h.slot) for h in handles} == {0, 1}
-for h, o in zip(handles, oracle):     # token-for-token vs 1-device int8
-    np.testing.assert_array_equal(np.asarray(h.output), o)
+# the 1-device int8 ring engine's tokens, until a near-tie in its logits
+# (int8 paths quantize different f32 values; the mesh reorders sums)
+from tests.conftest import assert_tokens_match_until_near_tie
+for r, h, o in zip(reqs, handles, oracle):
+    assert_tokens_match_until_near_tie(solo, r, h.output, o)
 st = eng.paged_stats()
 assert st["allocated"] == 0 and st["free"] == st["usable"], st
 # the int8 pools AND their f32 scale siblings live on the mesh (the
@@ -487,9 +512,10 @@ def test_depth_serving_spmd_parity(tmp_path):
 @pytest.mark.slow
 def test_quantized_serving_spmd_parity(tmp_path):
     """int8 KV + int8 weights on the 2x4 (data, model) mesh: the sharded
-    paged engine is token-for-token identical to the single-device int8
-    ring engine on a staggered mixed-budget workload, compile counts stay
-    flat, the pool drains, and every cache leaf (int8 pool + f32 scale
-    sibling) is placed on the mesh."""
+    paged engine serves the single-device int8 ring engine's tokens on a
+    staggered mixed-budget workload — identical until a near-tie in the
+    single-device logits — compile counts stay flat, the pool drains, and
+    every cache leaf (int8 pool + f32 scale sibling) is placed on the
+    mesh."""
     out = _run_spmd_script(_QUANT_SCRIPT)
     assert "QUANT-SPMD-PARITY-OK" in out, out

@@ -1,18 +1,18 @@
 """Sharding rule table: divisibility fitting, cache specs, input specs.
 
-Uses AbstractMesh (via the version-compatible ``abstract_mesh`` helper) so
-the production (16,16) axis sizes are exercised without 256 devices."""
+Uses AbstractMesh so (16, 16) axis sizes are exercised without 256
+devices."""
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import get_config
-from repro.runtime.sharding import (_fit_spec, abstract_mesh, batch_spec,
+from repro.runtime.sharding import (_fit_spec, batch_spec,
                                     cache_specs_tree, param_specs)
 
-MESH = abstract_mesh((16, 16), ("data", "model"))
-POD_MESH = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+POD_MESH = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_fit_spec_keeps_divisible():
@@ -20,8 +20,8 @@ def test_fit_spec_keeps_divisible():
 
 
 def test_fit_spec_replicates_indivisible_param_dims():
-    # qwen2 kv=4 heads can't shard 16-way -> replicate (NOT relocate to a
-    # contraction dim, which would force partial-sum all-reduces; §Perf H1)
+    # 4 kv heads cannot shard over a 16-wide axis -> replicate (NOT relocate
+    # to a contraction dim, which would force partial-sum all-reduces)
     assert _fit_spec(P(None, "model", None), (28, 4, 128), MESH) \
         == P(None, None, None)
 
