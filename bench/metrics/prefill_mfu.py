@@ -1,0 +1,19 @@
+"""Model step: the dense model's operations for the prompts admitted in
+the traced window, over the admission program's device time there and
+the chip's bf16 peak, in percent. The whole admission's share of the
+peak, beside the prefill kernels' rooflines."""
+import counts
+from trace import TraceError
+
+
+def read(red, rec, ctx):
+    d = ctx["dims"]
+    flops = sum(counts.prompt_flops(d, p)
+                for s in rec["steps"] for p in s["admitted"])
+    if not flops:
+        return None
+    t = red["program_s"].get("admit", 0.0)
+    if t <= 0:
+        raise TraceError("prompts admitted in the traced window but no "
+                         "admission program (jit_admit) ran")
+    return 100.0 * flops / (t * ctx["peaks"]["bf16_flops"])

@@ -1,0 +1,9 @@
+import os
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+for p in (BENCH, BENCH / "tests", BENCH.parent / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
