@@ -86,6 +86,7 @@ def token_router_init(key, d: int):
     return {"w": w, "b": jnp.zeros((), jnp.float32)}
 
 
+@jax.named_scope("router")
 def token_logits(rp, x):
     """Scalar routing logits per token. x: (..., D) -> (...,) f32."""
     return x.astype(jnp.float32) @ rp["w"] + rp["b"]
@@ -253,6 +254,7 @@ class RoutingPlan(NamedTuple):
     bucket: int
 
 
+@jax.named_scope("router")
 def make_plan(scores, k, bucket: int) -> RoutingPlan:
     """Build a RoutingPlan from router scores with ONE sort.
 
@@ -384,6 +386,7 @@ def is_full(v, limit=1.0):
     return jnp.asarray(v) >= limit
 
 
+@jax.named_scope("router")
 def token_gate(logits, scores, capacity, mode: str, *, theta=0.5,
                mxu: bool = False):
     """Unified keep-mask + router weight for input subset selection.
@@ -409,6 +412,7 @@ def token_gate(logits, scores, capacity, mode: str, *, theta=0.5,
     return keep, jnp.where(full, 1.0, keep * scores)
 
 
+@jax.named_scope("router")
 def bce_topk_loss(logits, in_topk):
     """§B.1 auxiliary loss: router sigmoid should predict top-k membership."""
     y = in_topk.astype(jnp.float32)
@@ -572,6 +576,7 @@ def param_router_init(key, d: int, m: int):
     return {"w": w}
 
 
+@jax.named_scope("router")
 def param_route_weights(rp, x, top_k, normalize_to_m: bool = True,
                         valid=None):
     """Alg. 1: w = M * softmax(W_r x); top-k selection mask.

@@ -225,6 +225,7 @@ def blocked_sdpa(q, k, v, q_pos, kv_pos, causal, window, kv_valid=None,
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
+@jax.named_scope("attention")
 def attn_apply(
     p, x, *, cfg, positions, causal: bool = True, window: int = 0,
     kv_x=None, kv_positions=None, kv_valid=None, kv_count=None,
@@ -278,6 +279,7 @@ def attn_apply(
     return out, k, v
 
 
+@jax.named_scope("attention")
 def attn_decode(
     p, x, cache, t, *, cfg, window: int = 0, head_weights=None, lora=None,
     use_rope: bool = True, write: Optional[jnp.ndarray] = None,
@@ -458,6 +460,7 @@ def _paged_gather(cache, table, B: int, dtype=None):
     return kg, vg, kvv, kvpos
 
 
+@jax.named_scope("attention")
 def attn_decode_paged(
     p, x, cache, t, table, trash, *, cfg, head_weights=None, lora=None,
     use_rope: bool = True, write: Optional[jnp.ndarray] = None,
@@ -532,6 +535,7 @@ def attn_decode_paged(
     return out, new_cache
 
 
+@jax.named_scope("attention")
 def attn_chunk(
     p, x, cache, write_page, table_row, pos0, plen, *, cfg, keep=None,
     head_weights=None, lora=None, use_rope: bool = True,

@@ -150,6 +150,7 @@ def _lora_gate(lora, cap, student):
     return {**lora, "scale": 1.0 - jnp.asarray(full, jnp.float32)}
 
 
+@jax.named_scope("router")
 def _head_weights(rp, h, spec, pol, cfg, auxes, valid=None):
     if rp is None or spec is None or "head" not in rp \
             or not spec.mha_head_routed:
@@ -175,6 +176,7 @@ def _mlp_fn(p, rp, cfg, spec, pol, elastic_on, mode, auxes, backend=None):
     dense MLP through ``kernels.ops.fused_mlp`` (``token_count`` becomes
     the kernel's scalar-prefetched ``valid_count``) and expert dispatch
     through ``kernels.ops.moe_gmm``."""
+    @jax.named_scope("mlp")
     def f(h, _pos, token_valid=None, dispatch_frac=None, token_count=None):
         if cfg.moe is not None:
             if elastic_on and rp and "expert" in rp and mode != "base":
@@ -333,6 +335,7 @@ def block_apply(
     depth_w_sel = None        # depth weight on the plan's selected set
     depth_gate = None         # infer-mode depth threshold gate (keep, w)
 
+    @jax.named_scope("router")
     def build_plan(h_src):
         """The block's ONE RoutingPlan sort, from the primary router.
         Under a mesh the plan arrays stay replicated over `model` (batch
@@ -352,6 +355,7 @@ def block_apply(
         else:
             auxes.append(R.RouteAux.of(keep=keep))
 
+    @jax.named_scope("router")
     def plan_weights(plan, logits, scores, h_src):
         """Mixer-stage weight on the plan's selected set: the primary
         router's scores times every secondary mixer router's, each
@@ -369,6 +373,7 @@ def block_apply(
             bce_aux(lg, plan.keep, train=True)
         return w_sel * plan.valid
 
+    @jax.named_scope("router")
     def mixer_gate(h_src):
         """Dense/threshold gate over every mixer-stage router. Train: the
         PRIMARY router rank-masks at the shared plan capacity (secondary
@@ -683,6 +688,7 @@ def _pad_cache(k, v, keep, max_len: int, window: int = 0,
 
 # ------------------------------ decode --------------------------------------
 
+@jax.named_scope("router")
 def _decode_token_gate(rp, name, h, cap, pol):
     """Threshold gate for one decode token: (keep (B,), weight (B,)).
     capacity >= 1 or student off forces (keep all, weight 1) per row."""
@@ -766,11 +772,13 @@ def block_decode(kind: str, p, rp, x, cache, t, *, cfg, spec, pol=None,
         pos = jnp.zeros((B, 1), jnp.int32)
         kvp = jnp.broadcast_to(jnp.arange(xc["k"].shape[1], dtype=jnp.int32),
                                xc["k"].shape[:2])
-        mask = A._mask(pos, kvp, False, 0, xc["valid"])
-        q = A._project_q(p["xattn"], hx, pos, cfg, None, False)
-        ctx = A.sdpa(q, xc["k"], xc["v"], mask)
-        x = x + jnp.einsum("bshk,hkd->bsd", ctx,
+        with jax.named_scope("attention"):
+            mask = A._mask(pos, kvp, False, 0, xc["valid"])
+            q = A._project_q(p["xattn"], hx, pos, cfg, None, False)
+            ctx = A.sdpa(q, xc["k"], xc["v"], mask)
+            y = jnp.einsum("bshk,hkd->bsd", ctx,
                            quant.maybe_dequant(p["xattn"], "wo", ctx.dtype))
+        x = x + y
 
     if has_mlp(kind):
         h = norm_apply(p["norm2"], x, cfg.norm)
@@ -781,22 +789,24 @@ def block_decode(kind: str, p, rp, x, cache, t, *, cfg, spec, pol=None,
         if keepd is not None:   # depth gates the MLP delta too
             keep2 = keepd if keep2 is None else keep2 & keepd
             w2 = wd if w2 is None else w2 * wd
-        if cfg.moe is not None:
-            if routed and "expert" in rp:
-                y, _ = moe_decode(p["mlp"], h, act=cfg.act,
+        with jax.named_scope("mlp"):
+            if cfg.moe is not None:
+                if routed and "expert" in rp:
+                    y, _ = moe_decode(p["mlp"], h, act=cfg.act,
+                                      router_w=rp["expert"]["w"],
+                                      normalize_to_m=True,
+                                      **_expert_args(pol, cfg.moe.n_experts))
+                else:
+                    y, _ = moe_decode(p["mlp"], h, act=cfg.act,
+                                      top_k=cfg.moe.top_k)
+            elif routed and "expert" in rp and spec.mlp_n_experts:
+                ep = moefy_mlp(p["mlp"], spec.mlp_n_experts)
+                y, _ = moe_decode(ep, h, act=cfg.act,
                                   router_w=rp["expert"]["w"],
                                   normalize_to_m=True,
-                                  **_expert_args(pol, cfg.moe.n_experts))
+                                  **_expert_args(pol, spec.mlp_n_experts))
             else:
-                y, _ = moe_decode(p["mlp"], h, act=cfg.act,
-                                  top_k=cfg.moe.top_k)
-        elif routed and "expert" in rp and spec.mlp_n_experts:
-            ep = moefy_mlp(p["mlp"], spec.mlp_n_experts)
-            y, _ = moe_decode(ep, h, act=cfg.act,
-                              router_w=rp["expert"]["w"], normalize_to_m=True,
-                              **_expert_args(pol, spec.mlp_n_experts))
-        else:
-            y = mlp_apply(p["mlp"], h, cfg.act)
+                y = mlp_apply(p["mlp"], h, cfg.act)
         if keep2 is not None:
             y = y * w2[:, None, None].astype(y.dtype)
         x = x + y

@@ -392,10 +392,10 @@ def forward(params, rparams, batch, cfg, ecfg=None, mode: str = "base",
                         period=period, causal=True, enc_kv=enc_kv,
                         enc_valid=enc_valid, remat=remat, bucket=bucket)
     aux = aux + aux0
-    x = norm_apply(params["final_norm"], x, cfg.norm)
-    if return_hidden:
-        return x, aux
-    return _logits(params, cfg, x), aux
+    with jax.named_scope("lm_head"):
+        x = norm_apply(params["final_norm"], x, cfg.norm)
+        out = x if return_hidden else _logits(params, cfg, x)
+    return out, aux
 
 
 # ------------------------------ serving --------------------------------------
@@ -464,8 +464,9 @@ def prefill(params, rparams, batch, cfg, ecfg=None, mode: str = "infer",
             static_pol=static_pol, pol=pol):
         x, _, nc = apply_block(ent, lp, lrp, lpol, x)
         tail_caches.append(nc)
-    x = norm_apply(params["final_norm"], x, cfg.norm)
-    logits = _logits(params, cfg, x[:, -1])
+    with jax.named_scope("lm_head"):
+        x = norm_apply(params["final_norm"], x, cfg.norm)
+        logits = _logits(params, cfg, x[:, -1])
     return logits, {"scan": scan_caches, "tail": tail_caches}
 
 
@@ -568,8 +569,9 @@ def decode_step(params, rparams, token, caches, t, cfg, ecfg=None,
                              elastic_on=ent.elastic, window=ent.window,
                              table=table, trash=trash)
         new_tail.append(nc)
-    x = norm_apply(params["final_norm"], x, cfg.norm)
-    logits = _logits(params, cfg, x[:, -1])
+    with jax.named_scope("lm_head"):
+        x = norm_apply(params["final_norm"], x, cfg.norm)
+        logits = _logits(params, cfg, x[:, -1])
     return logits, {"scan": new_scan, "tail": new_tail}
 
 
@@ -645,11 +647,13 @@ def prefill_chunk_step(params, rparams, tokens, caches, write_page, table_row,
                             spec=spec, pol=(pol if static_pol else lpol),
                             mode=mode, elastic_on=ent.elastic)
         new_tail.append(nc)
-    x = norm_apply(params["final_norm"], x, cfg.norm)
-    lidx = jnp.clip(jnp.asarray(plen, jnp.int32) - 1
-                    - jnp.asarray(pos0, jnp.int32), 0, x.shape[1] - 1)
-    h_last = jax.lax.dynamic_index_in_dim(x, lidx, axis=1, keepdims=False)
-    logits = _logits(params, cfg, h_last)
+    with jax.named_scope("lm_head"):
+        x = norm_apply(params["final_norm"], x, cfg.norm)
+        lidx = jnp.clip(jnp.asarray(plen, jnp.int32) - 1
+                        - jnp.asarray(pos0, jnp.int32), 0, x.shape[1] - 1)
+        h_last = jax.lax.dynamic_index_in_dim(x, lidx, axis=1,
+                                              keepdims=False)
+        logits = _logits(params, cfg, h_last)
     return logits, {"scan": new_scan, "tail": new_tail}
 
 

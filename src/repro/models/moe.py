@@ -70,6 +70,7 @@ def _expert_ffn(p, x_sel, act, backend=None, counts=None):
                       quant.maybe_dequant(p, "wo", x_sel.dtype)).astype(x_sel.dtype)
 
 
+@jax.named_scope("mlp")
 def moe_apply(
     p, x, *, act: str, top_k: int, router_w=None, normalize_to_m: bool = False,
     capacity_factor: float = 1.25, seq_chunk: int = 2048, top_k_traced=None,
@@ -122,46 +123,47 @@ def moe_apply(
 
     def one_chunk(xc, vc, tvc):
         s = xc.shape[1]
-        logits = xc.astype(jnp.float32) @ rw                  # (B,s,E)
-        probs = jax.nn.softmax(logits, axis=-1)
-        w = probs * E if normalize_to_m else probs
-        cap_eff = None
-        kept = chunk if dispatch_frac is None else jnp.clip(
-            jnp.ceil(dispatch_frac * chunk - 1e-9), 1, chunk)
-        if top_k_traced is None:
-            mask = topk_mask(w, k) & vc[None, :, None]
-            k_for_cap = k
-        else:
-            kt = jnp.clip(top_k_traced, 1, E)
-            full = bcast_to(is_full(top_k_traced, E), w.ndim)
-            w = jnp.where(full, 1.0, w)
-            mask = topk_mask_dyn(w, kt) & vc[None, :, None]
-            k_for_cap = kt
-        if tvc is not None:
-            mask = mask & tvc[:, :, None]
-        if top_k_traced is not None or dispatch_frac is not None:
-            # per-expert capacity the static path would have compiled for
-            # this budget (buffers stay sized for the static maximum `cap`)
-            ce = jnp.ceil(k_for_cap * kept / E * capacity_factor)
-            cap_eff = jnp.minimum(kept,
-                                  jnp.maximum(4, jnp.ceil(ce / 4) * 4))
-        # load-balance stats over REAL tokens only: chunk padding and the
-        # ragged bucket's invalid tail must not dilute the denominator
-        # (else budgets sharing a bucket train against a weaker signal
-        # than the per-budget gather compile would have)
-        stat_w = jnp.broadcast_to(vc[None, :, None].astype(jnp.float32),
-                                  mask.shape[:2] + (1,))
-        if tvc is not None:
-            stat_w = stat_w * tvc[:, :, None].astype(jnp.float32)
-        denom = jnp.maximum(jnp.sum(stat_w), 1.0)
-        red_frac = jnp.sum(mask * stat_w, axis=(0, 1)) / denom
-        load = E * jnp.sum(
-            red_frac * jnp.sum(probs * stat_w, axis=(0, 1)) / denom)
-        sc = jnp.where(mask, w, -jnp.inf)                     # (B,s,E)
-        vals, idx = jax.lax.top_k(sc.transpose(0, 2, 1), cap)  # (B,E,C)
-        keep = jnp.isfinite(vals)
-        if cap_eff is not None:
-            keep &= jnp.arange(cap)[None, None, :] < bcast_to(cap_eff, 3)
+        with jax.named_scope("router"):
+            logits = xc.astype(jnp.float32) @ rw                  # (B,s,E)
+            probs = jax.nn.softmax(logits, axis=-1)
+            w = probs * E if normalize_to_m else probs
+            cap_eff = None
+            kept = chunk if dispatch_frac is None else jnp.clip(
+                jnp.ceil(dispatch_frac * chunk - 1e-9), 1, chunk)
+            if top_k_traced is None:
+                mask = topk_mask(w, k) & vc[None, :, None]
+                k_for_cap = k
+            else:
+                kt = jnp.clip(top_k_traced, 1, E)
+                full = bcast_to(is_full(top_k_traced, E), w.ndim)
+                w = jnp.where(full, 1.0, w)
+                mask = topk_mask_dyn(w, kt) & vc[None, :, None]
+                k_for_cap = kt
+            if tvc is not None:
+                mask = mask & tvc[:, :, None]
+            if top_k_traced is not None or dispatch_frac is not None:
+                # per-expert capacity the static path would have compiled for
+                # this budget (buffers stay sized for the static maximum `cap`)
+                ce = jnp.ceil(k_for_cap * kept / E * capacity_factor)
+                cap_eff = jnp.minimum(kept,
+                                      jnp.maximum(4, jnp.ceil(ce / 4) * 4))
+            # load-balance stats over REAL tokens only: chunk padding and the
+            # ragged bucket's invalid tail must not dilute the denominator
+            # (else budgets sharing a bucket train against a weaker signal
+            # than the per-budget gather compile would have)
+            stat_w = jnp.broadcast_to(vc[None, :, None].astype(jnp.float32),
+                                      mask.shape[:2] + (1,))
+            if tvc is not None:
+                stat_w = stat_w * tvc[:, :, None].astype(jnp.float32)
+            denom = jnp.maximum(jnp.sum(stat_w), 1.0)
+            red_frac = jnp.sum(mask * stat_w, axis=(0, 1)) / denom
+            load = E * jnp.sum(
+                red_frac * jnp.sum(probs * stat_w, axis=(0, 1)) / denom)
+            sc = jnp.where(mask, w, -jnp.inf)                     # (B,s,E)
+            vals, idx = jax.lax.top_k(sc.transpose(0, 2, 1), cap)  # (B,E,C)
+            keep = jnp.isfinite(vals)
+            if cap_eff is not None:
+                keep &= jnp.arange(cap)[None, None, :] < bcast_to(cap_eff, 3)
         # dispatch: token gather into (B,E,C,D) buffers (UNweighted)
         x_sel = jnp.take_along_axis(xc[:, None], idx[..., None], axis=2)
         # per-(b,e) occupancy: top_k returns descending, so the kept slots
@@ -216,6 +218,7 @@ def _dense_ffn(p, x, act):
     return (h @ quant.maybe_dequant(p, "wo", x.dtype)).astype(x.dtype)
 
 
+@jax.named_scope("mlp")
 def moe_decode(p, x, *, act: str, top_k: int, router_w=None,
                normalize_to_m: bool = False, top_k_traced=None):
     """Decode path (S==1): gather only the selected experts' weights so HBM
@@ -228,15 +231,16 @@ def moe_decode(p, x, *, act: str, top_k: int, router_w=None,
     rw = router_w if router_w is not None else p["router"]
     E = rw.shape[-1]
     k = min(top_k, E)
-    logits = x.astype(jnp.float32) @ rw                       # (B,1,E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    w = probs * E if normalize_to_m else probs
-    vals, idx = jax.lax.top_k(w[:, 0], k)                     # (B,k)
-    if top_k_traced is not None:
-        kt = jnp.clip(top_k_traced, 1, E)
-        sel = jnp.arange(k)[None, :] < bcast_to(kt, 2)        # (B,k)
-        full = bcast_to(is_full(top_k_traced, E), 2)
-        vals = jnp.where(full, 1.0, jnp.where(sel, vals, 0.0))
+    with jax.named_scope("router"):
+        logits = x.astype(jnp.float32) @ rw                   # (B,1,E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        w = probs * E if normalize_to_m else probs
+        vals, idx = jax.lax.top_k(w[:, 0], k)                 # (B,k)
+        if top_k_traced is not None:
+            kt = jnp.clip(top_k_traced, 1, E)
+            sel = jnp.arange(k)[None, :] < bcast_to(kt, 2)    # (B,k)
+            full = bcast_to(is_full(top_k_traced, E), 2)
+            vals = jnp.where(full, 1.0, jnp.where(sel, vals, 0.0))
     def take_w(name):
         # gather selected experts' weights, then dequant the gathered
         # slice only — HBM traffic stays ∝ top-k int8 expert rows
