@@ -246,7 +246,12 @@ def decode_attention(q, k, v, kv_pos, t, kv_valid=None, kscale=None,
 def paged_decode_attention(q, kp, vp, table, t, pvalid, kscale=None,
                            vscale=None, *, backend=None):
     """Paged-pool decode attention (see kernels/paged_decode_attention.py).
-    kscale/vscale: (N, ps, K) f32 dequant scale pools for int8 kp/vp.
+    q: (B, 1, H, Dh); kp/vp: (N, ps, K, Dh) page pool, read in place;
+    table: (B, P) page-table rows (-1 = unused); t: (B,) positions;
+    pvalid: (N, ps) routing validity; kscale/vscale: (N, ps, K) f32
+    dequant scale pools for int8 kp/vp. The kernel runs one grid step per
+    slot and reads only the pages of its entries 0 .. t // ps, all kv
+    heads of a page at once, so its work follows the live context, not P.
     Inference-only: no VJP (decode is never differentiated)."""
     kb = resolve_backend(backend)
     if kb == "ref":

@@ -38,7 +38,9 @@ replica axis up/down live (in-flight requests resume bitwise).
 Under the JAX profiler ``step()`` records its phases as host spans
 (``serve.step`` around ``serve.schedule``, ``serve.admit`` with its
 ``serve.admit.prefix``/``.call``/``.sync``, ``serve.pages``,
-``serve.upload``, ``serve.decode``, ``serve.sync``, ``serve.emit``), and
+``serve.upload``, ``serve.decode`` with its ``live`` slots and, paged,
+the ``pages`` of their tables the decode kernel visits, ``serve.sync``,
+``serve.emit``), and
 every compiled operation carries the model's named scope (``attention``,
 ``mlp``, ``router``, ``lm_head``, ``sample``) in its metadata. With the
 profiler off a span costs about a microsecond and scopes cost nothing.
@@ -1027,7 +1029,11 @@ class ServingEngine:
                 if paged:
                     ops += (jnp.asarray(self._table),
                             jnp.asarray(self._trash))
-            with TraceAnnotation("serve.decode", live=len(live)):
+            # table entries the paged kernel visits: 0 .. t // ps a slot
+            pages = (int((self._t[self._active] // self.page_size + 1).sum())
+                     if paged else 0)
+            with TraceAnnotation("serve.decode", live=len(live),
+                                 pages=pages):
                 self._tok, self._caches = self._step_fn(
                     self.params, self.rp, self._tok, self._caches, *ops)
         with TraceAnnotation("serve.sync"):

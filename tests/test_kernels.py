@@ -10,6 +10,7 @@ from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fused_mlp import fused_mlp
 from repro.kernels.moe_gmm import moe_gmm
+from repro.kernels.paged_decode_attention import paged_decode_attention
 
 TOLS = {jnp.float32: dict(atol=2e-5, rtol=2e-5),
         jnp.bfloat16: dict(atol=5e-2, rtol=5e-2)}
@@ -266,3 +267,89 @@ def test_flash_matches_model_blocked_sdpa(key):
                                atol=2e-5)
     np.testing.assert_allclose(np.asarray(dense), np.asarray(kernel),
                                atol=2e-5)
+
+
+def _paged_sweep_rows(P, ps):
+    """(t, table row) per slot, one case a row; page ids are placeholders
+    (letters) bound to pool pages by the caller."""
+    full = [f"f{i}" for i in range(P)]
+    return [
+        # a long table with few live pages; entries past t name pages, as
+        # the engine's pre-allocation for the next writes leaves them
+        (ps + 4, ["a0", "a1", "a2", "a3"]),
+        (5 * ps + 7, [f"b{i}" for i in range(6)]),          # t mid-page
+        (3 * ps + ps - 1, [f"c{i}" for i in range(5)]),     # last lane
+        (0, ["d0", "d1"]),                                  # t = 0
+        (P * ps - 1, full),                                 # a full row
+        # -1 holes inside the live range (11 entries: no whole number of
+        # blocks of 8 pages either)
+        (10 * ps + 3, ["e0", "e1", -1, "e3", "e4", "e5", "e6", -1, "e8",
+                       "e9", "e10", "e11"]),
+        # shares its first two pages with the mid-page row
+        (2 * ps + 9, ["b0", "b1", "g2", "g3"]),
+        (ps + 2, ["z0", "z1", "z2"]),    # every live lane routed out
+    ]
+
+
+@pytest.mark.parametrize("H,K", [(28, 4), (4, 2)])       # GQA 7:1, 2:1
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_paged_decode_attention_sweep(H, K, kv):
+    """Paged decode kernel vs the jnp oracle over per-slot cases (see
+    _paged_sweep_rows), with pvalid holes throughout. Every pool page no
+    live entry references (entries past t, the page -1 entries clamp to)
+    holds NaN — in the pages themselves for bf16, in the scale pools for
+    int8: the kernel must neither read them nor let them leak, so its
+    output is finite and equals the oracle on the pool with those pages
+    zeroed."""
+    ps, P, Dh = 16, 24, 128
+    rows = _paged_sweep_rows(P, ps)
+    B = len(rows)
+    names = sorted({e for _, r in rows for e in r if e != -1},
+                   key=lambda s: (s[0], int(s[1:])))
+    rng = np.random.default_rng(7)
+    N = len(names) + 2
+    ids = dict(zip(names, rng.permutation(N)[:len(names)].tolist()))
+    table = np.full((B, P), -1, np.int32)
+    for b, (_, r) in enumerate(rows):
+        table[b, :len(r)] = [ids.get(e, -1) for e in r]
+    t = np.asarray([tt for tt, _ in rows], np.int32)
+    live = np.zeros(N, bool)
+    for b in range(B):
+        e = table[b, :t[b] // ps + 1]
+        live[e[e >= 0]] = True
+    pvalid = rng.random((N, ps)) < 0.8
+    pvalid[ids["z0"]] = pvalid[ids["z1"]] = False
+    q = jnp.asarray(rng.normal(size=(B, 1, H, Dh)),
+                    jnp.bfloat16 if kv == "bf16" else jnp.float32)
+    dead = ~live[:, None, None, None]
+    if kv == "bf16":
+        kp, vp = (rng.normal(size=(N, ps, K, Dh)) for _ in range(2))
+        scales = {}
+        clean = dict(kp=np.where(dead, 0.0, kp), vp=np.where(dead, 0.0, vp))
+        kp, vp = np.where(dead, np.nan, kp), np.where(dead, np.nan, vp)
+        kp, vp = (jnp.asarray(x, jnp.bfloat16) for x in (kp, vp))
+        clean = {k: jnp.asarray(v, jnp.bfloat16) for k, v in clean.items()}
+    else:
+        kp, vp = (jnp.asarray(rng.integers(-127, 128, (N, ps, K, Dh)),
+                              jnp.int8) for _ in range(2))
+        ks, vs = (rng.uniform(0.005, 0.02, (N, ps, K)) for _ in range(2))
+        clean = dict(kp=kp, vp=vp,
+                     kscale=jnp.asarray(np.where(dead[..., 0], 0.0, ks),
+                                        jnp.float32),
+                     vscale=jnp.asarray(np.where(dead[..., 0], 0.0, vs),
+                                        jnp.float32))
+        scales = dict(kscale=jnp.asarray(np.where(dead[..., 0], np.nan, ks),
+                                         jnp.float32),
+                      vscale=jnp.asarray(np.where(dead[..., 0], np.nan, vs),
+                                         jnp.float32))
+    table, t, pvalid = (jnp.asarray(x) for x in (table, t, pvalid))
+    got = paged_decode_attention(q, kp, vp, table, t, pvalid, **scales,
+                                 interpret=True)
+    want = ref.paged_decode_attention_ref(
+        q, clean["kp"], clean["vp"], table, t, pvalid,
+        kscale=clean.get("kscale"), vscale=clean.get("vscale"))
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(got).all()
+    assert (got[-1] == 0).all()            # no attendable key: exact zeros
+    tol = TOLS[jnp.bfloat16 if kv == "bf16" else jnp.float32]
+    np.testing.assert_allclose(got, want, **tol)

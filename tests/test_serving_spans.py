@@ -156,6 +156,21 @@ def test_one_decode_and_sync_per_step_with_live_slots(traced):
     assert n_decode >= max(len(h.output) for h in handles) - 1
 
 
+def test_decode_span_counts_the_table_entries_the_kernel_visits(traced):
+    """``pages`` of a paged decode: entries 0 .. t // page_size of every
+    live slot's table row, t the position the step writes; 0 on the ring."""
+    layout, _engine_, handles, spans = traced
+    decs = _named(spans, "serve.decode")
+    if layout == "ring":
+        assert all(d[3]["pages"] == 0 for d in decs)
+        return
+    for d in decs:
+        assert d[3]["live"] <= d[3]["pages"] <= d[3]["live"] * (48 // PAGE)
+    want = sum((len(h.request.prompt) + j) // PAGE + 1
+               for h in handles for j in range(len(h.output) - 1))
+    assert sum(d[3]["pages"] for d in decs) == want
+
+
 def test_tracing_changes_no_compile_and_no_token(traced):
     layout, engine, handles, _spans = traced
     plain = _engine(layout)

@@ -32,6 +32,7 @@ E = get_elastic("qwen2-7b", CFG).mlp_n_experts      # moefied experts
 FE = F // E                                          # 1184: not a x128 tile
 B, L, T = 8, 1024, 256            # serving slots, ring length, prompt rows
 N_PAGES, PS = 513, 16             # page pool, page size
+SB, SP, SN = 32, 128, 4097          # paged serving: slots, table, pool
 BF, F32, I32, I8, BOOL = (jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8,
                           jnp.bool_)
 
@@ -89,6 +90,14 @@ CASES = {
          ((N_PAGES, PS, K, Dh), I8), ((B, L // PS), I32), ((B,), I32),
          ((N_PAGES, PS), BOOL), ((N_PAGES, PS, K), F32),
          ((N_PAGES, PS, K), F32)]),
+    # the paged serving cell's own shapes: 32 slots, 128-entry tables
+    # (2048 positions), the 4097-page int8 pool with its f32 scale pools
+    "paged_decode_attention_int8_serving": (
+        lambda q, kp, vp, tb, t, pv, ks, vs: PA.paged_decode_attention(
+            q, kp, vp, tb, t, pv, kscale=ks, vscale=vs),
+        [((SB, 1, H, Dh), BF), ((SN, PS, K, Dh), I8), ((SN, PS, K, Dh), I8),
+         ((SB, SP), I32), ((SB,), I32), ((SN, PS), BOOL),
+         ((SN, PS, K), F32), ((SN, PS, K), F32)]),
     "fused_mlp": (
         lambda x, wi, wo, wg, n: FM.fused_mlp(x, wi, wo, wg, valid_count=n),
         [((2, T, D), BF), ((D, F), BF), ((F, D), BF), ((D, F), BF),
