@@ -1,4 +1,4 @@
-"""Plain reference of the served model: Qwen2 with ElastiFormer routers.
+"""Plain reference of a qwen2 configuration: Qwen2 with ElastiFormer routers.
 
 Straight ``jax.numpy`` in float32 at ``precision="highest"``, one whole
 sequence at a time, no cache, no kernels, and nothing imported from the

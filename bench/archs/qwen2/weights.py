@@ -1,19 +1,18 @@
-"""Weights for a configuration, made on the device from ``--seed``.
+"""Weights of a qwen2 configuration, made on the device from ``--seed``.
 
 The benchmark makes the weights itself, in the layout the serving engine
 takes (``{"scan": [per-layer leaves stacked over layers], "tail": []}``),
 so that the plain reference in ``reference.py`` reads the very same arrays
-without taking anything the program under test made. Everything is made
-in one jitted call whose only argument is the key, so every seed reuses
-one compiled program.
+without taking anything the program under test made. ``archs.make`` makes
+them in one jitted call whose only argument is the key.
 """
 from __future__ import annotations
 
 import math
 
-import jax
 import jax.numpy as jnp
-import numpy as np
+
+import archs
 
 
 def dims(cfg: dict) -> dict:
@@ -25,14 +24,6 @@ def dims(cfg: dict) -> dict:
             "F": cfg["intermediate_size"], "V": cfg["vocab_size"],
             "E": cfg["elastic"]["mlp_n_experts"] or 0,
             "r": cfg["elastic"]["lora_rank"]}
-
-
-def seed_key(seed: int):
-    """A PRNG key from any non-negative integer seed (64 bits at most)."""
-    seed = int(seed)
-    if seed < 0:
-        raise ValueError(f"seed must be >= 0, got {seed}")
-    return np.array([seed >> 32 & 0xFFFFFFFF, seed & 0xFFFFFFFF], np.uint32)
 
 
 def _spec(cfg: dict):
@@ -78,26 +69,7 @@ def _spec(cfg: dict):
     return out
 
 
-def _make(key, cfg: dict):
-    trees = {"params": {"scan": [{}], "tail": []},
-             "rp": {"scan": [{}], "tail": []}}
-    base = jax.random.wrap_key_data(key, impl="threefry2x32")
-    for n, (path, shape, dtype, (kind, std)) in enumerate(_spec(cfg)):
-        k = jax.random.fold_in(base, n)
-        x = jax.random.normal(k, shape, jnp.float32)
-        x = 1.0 + 0.1 * x if kind == "norm" else x * std
-        node = trees
-        for p in path[:-1]:
-            node = node[p] if isinstance(p, int) else node.setdefault(p, {})
-        node[path[-1]] = x.astype(dtype)
-    return trees["params"], trees["rp"]
-
-
-def make_weights(cfg: dict, seed: int):
-    """(params, router params) on the default device, from the seed."""
-    fn = jax.jit(lambda k: _make(k, cfg))
-    return fn(jnp.asarray(seed_key(seed)))
-
-
-def weight_bytes(params) -> int:
-    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+def make_weights(cfg: dict, seed: int, shardings=None):
+    """(params, router params) from the seed, placed by ``shardings`` where
+    given, else on the default device."""
+    return archs.make(_spec(cfg), seed, shardings)

@@ -1,6 +1,7 @@
 """Model step: the dense model's operations for the tokens the decode
 program emitted in the traced window (each at its context), over the
-decode program's device time there and the chip's bf16 peak, in percent.
+decode program's device time there (the mean over the chips used) and
+their bf16 peak, all chips together, in percent.
 The whole decode step's share of the peak, beside the decode kernels'
 rooflines."""
 import counts
@@ -17,4 +18,5 @@ def read(red, rec, ctx):
     if t <= 0:
         raise TraceError("decode tokens in the traced window but no "
                          "decode program (jit_step) ran")
-    return 100.0 * flops / (t * ctx["peaks"]["bf16_flops"])
+    n = ctx["chips"]
+    return 100.0 * flops / (t * ctx["peaks"]["bf16_flops"] * n)

@@ -1,7 +1,8 @@
 """Model step: the dense model's operations for the prompts admitted in
-the traced window, over the admission program's device time there and
-the chip's bf16 peak, in percent. The whole admission's share of the
-peak, beside the prefill kernels' rooflines."""
+the traced window, over the admission program's device time there (the
+mean over the chips used) and their bf16 peak, all chips together, in
+percent. The whole admission's share of the peak, beside the prefill
+kernels' rooflines."""
 import counts
 from trace import TraceError
 
@@ -16,4 +17,5 @@ def read(red, rec, ctx):
     if t <= 0:
         raise TraceError("prompts admitted in the traced window but no "
                          "admission program (jit_admit) ran")
-    return 100.0 * flops / (t * ctx["peaks"]["bf16_flops"])
+    n = ctx["chips"]
+    return 100.0 * flops / (t * ctx["peaks"]["bf16_flops"] * n)

@@ -5,11 +5,18 @@
 
 A cell of ``BENCHMARK.json`` names a configuration
 (``bench/configs/<config>.json``) and a traffic mix
-(``bench/workloads/<traffic>.json``). The run makes the
+(``bench/workloads/<traffic>.json``). The configuration's ``model_type``
+names the directory ``bench/archs/<model_type>/`` that holds what is its
+architecture's own: its mapping to the program's configuration, its
+weights and its plain reference (``archs/__init__.py``). The run makes the
 configuration's weights on the chip from the seed, builds the program's
 ``ServingEngine`` from the configuration, warms the programs the cell's
 traffic uses, then offers the mix open-loop for ``--seconds`` through
 ``submit()``/``step()``. Each request is timed from when it was due.
+
+A cell on more than one chip runs on a ``(data=1, model=chips)`` mesh:
+the weights are made where the program's sharding rules place them, the
+engine is given the mesh, and the warm-up and the window run under it.
 
 ``--trace 0`` prints the cell's end-to-end metrics; ``--trace 1`` profiles
 the stretches the cell's ``trace`` names (``stretches`` of ``stretch_s``
@@ -18,12 +25,13 @@ their sum, each read by its own reader in ``bench/metrics/<metric>.py``.
 
 After the window a sample of finished requests, drawn from the seed and
 holding the longest, is compared with the plain reference
-(``reference.py``): at each served token, the gap between its logit and
-the reference's best at that position. Each statistic of those gaps that
-the cell's ``check.limits`` names (``max_gap``, ``median_gap``,
-``far_share`` of the requests at the cell's budget; with a suffix
-``_b<budget>``, of those of a class at another budget) must stay within
-its limit, and no program may have compiled inside the window.
+(``archs/<model_type>/reference.py``): at each served token, the gap
+between its logit and the reference's best at that position. Each
+statistic of those gaps that the cell's ``check.limits`` names
+(``max_gap``, ``median_gap``, ``far_share`` of the requests at the cell's
+budget; with a suffix ``_b<budget>``, of those of a class at another
+budget) must stay within its limit, and no program may have compiled
+inside the window.
 
 The last line of standard output is one JSON object (``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1``
@@ -51,12 +59,15 @@ import json  # noqa: E402
 import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
+from contextlib import nullcontext  # noqa: E402
 from pathlib import Path  # noqa: E402
 
 import numpy as np  # noqa: E402
 
 BENCH = Path(__file__).resolve().parent
 sys.path.insert(0, str(BENCH))
+
+import archs  # noqa: E402
 
 # a served token whose logit lies this far below the reference's best was
 # picked after a different upstream decision (a router flipped) or a fault:
@@ -129,41 +140,35 @@ def check_device(chips: int, bench_dir: Path, require_tpu: bool):
 
 # ------------------------------ building ------------------------------------
 
-def model_config(conf: dict, overrides: dict):
-    """The program's ModelConfig and ElasticConfig for a configuration file:
-    the registry entry with every size the file states."""
-    import dataclasses
-    from repro.configs import get_config, get_elastic
-    base = get_config(conf["registry_name"])
-    D, H = conf["hidden_size"], conf["num_attention_heads"]
-    cfg = dataclasses.replace(
-        base, n_layers=conf["num_hidden_layers"], d_model=D, n_heads=H,
-        n_kv_heads=conf["num_key_value_heads"], d_head=D // H,
-        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-        rope_theta=conf["rope_theta"], tie_embeddings=False,
-        dtype=conf["torch_dtype"], eos_id=None)
-    if cfg.padded_vocab != cfg.vocab_size:
-        raise SystemExit("bench: the vocabulary must be a multiple of 128")
-    el = conf["elastic"]
-    ecfg = dataclasses.replace(
-        get_elastic(conf["registry_name"], cfg),
-        mha_token_capacity=el["mha_token_capacity"],
-        mlp_token_capacity=el["mlp_token_capacity"],
-        mha_head_topk=el["mha_head_topk"],
-        mlp_n_experts=el["mlp_n_experts"] or None,
-        mlp_expert_topk=el["mlp_expert_topk"] or None,
-        lora_rank=el["lora_rank"], **overrides)
-    return cfg, ecfg
+def program_layout(cfg, ecfg):
+    """Shapes and dtypes of (params, router params) as the program's own
+    initialisers give them."""
+    import jax
+    from repro.models import model_init, router_init
+    key = jax.random.PRNGKey(0)
+    return (jax.eval_shape(lambda k: model_init(k, cfg, ecfg), key),
+            jax.eval_shape(lambda k: router_init(k, cfg, ecfg), key))
+
+
+def mesh_placement(cfg, ecfg, chips: int):
+    """A ``(data=1, model=chips)`` mesh and the shardings (params, router
+    params) the program gives its weights on it: the base weights by its
+    own rules (``repro.runtime.sharding.param_shardings``), the routers
+    replicated, as the engine places them."""
+    import jax
+    from repro.runtime import make_mesh
+    from repro.runtime.sharding import param_shardings, replicated
+    mesh = make_mesh((1, chips), ("data", "model"))
+    params, rp = program_layout(cfg, ecfg)
+    return mesh, (param_shardings(params, mesh),
+                  jax.tree.map(lambda _: replicated(mesh), rp))
 
 
 def check_layout(cfg, ecfg, params, rp) -> None:
     """The weights the benchmark made must have exactly the shapes and
     dtypes the program's own initialisers would give."""
     import jax
-    from repro.models import model_init, router_init
-    key = jax.random.PRNGKey(0)
-    want = (jax.eval_shape(lambda k: model_init(k, cfg, ecfg), key),
-            jax.eval_shape(lambda k: router_init(k, cfg, ecfg), key))
+    want = program_layout(cfg, ecfg)
     got = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                        (params, rp))
     sig = lambda t: jax.tree.map(lambda x: (x.shape, str(x.dtype)), t)
@@ -172,14 +177,19 @@ def check_layout(cfg, ecfg, params, rp) -> None:
                          f"\n want {sig(want)}\n got  {sig(got)}")
 
 
-def build_engine(cfg, ecfg, conf: dict, params, rp):
+def build_engine(cfg, ecfg, conf: dict, params, rp, mesh=None):
     from repro.training import ServingEngine
     e = conf["engine"]
     return ServingEngine(params, rp, cfg, ecfg, mode=e["mode"],
                          batch_size=e["slots"], max_seq=e["max_seq"],
                          theta=conf["elastic"]["theta"],
                          kv_layout=e["kv_layout"], page_size=e["page_size"],
-                         kv_dtype=e["kv_dtype"])
+                         kv_dtype=e["kv_dtype"], mesh=mesh)
+
+
+def under_mesh(s: dict):
+    """The context the engine's calls run in: its mesh, if it has one."""
+    return s["mesh"] if s["mesh"] is not None else nullcontext()
 
 
 def gen_request(r, i):
@@ -372,11 +382,11 @@ def gap_stats(gaps) -> dict:
             "far_share": float(np.mean(g > FAR_GAP))}
 
 
-def compare(params, rp, conf, cell, picked, budget, control=None) -> dict:
+def compare(served_gaps, params, rp, conf, cell, picked, budget,
+            control=None) -> dict:
     """The gaps of the served tokens of requests at ``budget`` below the
-    reference's best logit, and with a control those of the control's own
-    tokens."""
-    from reference import served_gaps
+    reference's best logit (``served_gaps`` of the architecture's
+    reference), and with a control those of the control's own tokens."""
     length = cell["check"]["ref_length"]
     prog, ctl, n = [], [], 0
     t = time.perf_counter()
@@ -447,6 +457,7 @@ def device_info(devs) -> dict:
 def setup(args, bench_dir, require_tpu, overrides, fault):
     """Everything before the window. Returns a dict of what it built."""
     cell, conf = load_cell(args.workload, bench_dir)
+    arch = archs.load(conf, bench_dir)
     devs, peaks = check_device(cell["chips"], bench_dir, require_tpu)
     import jax
     global _WATCHING
@@ -458,23 +469,29 @@ def setup(args, bench_dir, require_tpu, overrides, fault):
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     log(f"compile cache: {enable_compile_cache()}")
-    from weights import make_weights, weight_bytes
-    cfg, ecfg = model_config(conf, overrides or {})
-    params, rp = make_weights(conf, args.seed)
+    cfg, ecfg = arch.program.model_config(conf, overrides or {})
+    mesh, shardings = None, None
+    if cell["chips"] > 1:
+        mesh, shardings = mesh_placement(cfg, ecfg, cell["chips"])
+    params, rp = arch.weights.make_weights(conf, args.seed, shardings)
     check_layout(cfg, ecfg, params, rp)
-    log(f"weights: {weight_bytes(params) / 1e9:.2f} GB, "
-        f"{conf['num_hidden_layers']} layers, seed {args.seed}")
-    engine = build_engine(cfg, ecfg, conf, params, rp)
+    log(f"weights: {archs.weight_bytes(params) / 1e9:.2f} GB, "
+        f"{conf['num_hidden_layers']} layers, seed {args.seed}"
+        + (f", mesh {dict(mesh.shape)}" if mesh is not None else ""))
+    engine = build_engine(cfg, ecfg, conf, params, rp, mesh)
     if fault is not None:
         fault(engine)
+    s = {"cell": cell, "conf": conf, "arch": arch, "devs": devs,
+         "peaks": peaks, "params": params, "rp": rp, "engine": engine,
+         "mesh": mesh}
     import traffic
     V = conf["vocab_size"]
-    for r in traffic.warmup(cell, args.seed, V):
-        engine.submit(gen_request(r, 0))
-        drain(engine)
+    with under_mesh(s):
+        for r in traffic.warmup(cell, args.seed, V):
+            engine.submit(gen_request(r, 0))
+            drain(engine)
     log(f"warm: compile counts {engine.compile_counts()}")
-    return {"cell": cell, "conf": conf, "devs": devs, "peaks": peaks,
-            "params": params, "rp": rp, "engine": engine}
+    return s
 
 
 def sweep(args, s) -> None:
@@ -485,10 +502,11 @@ def sweep(args, s) -> None:
         c = dict(cell, arrivals={"kind": "poisson", "rate": rate})
         reqs = traffic.generate(c, args.seconds, args.seed,
                                 s["conf"]["vocab_size"])
-        rec = run_window(engine, reqs, c, args.seconds)
-        pending = rec["pending"]
-        t = time.perf_counter()
-        drain(engine)
+        with under_mesh(s):
+            rec = run_window(engine, reqs, c, args.seconds)
+            pending = rec["pending"]
+            t = time.perf_counter()
+            drain(engine)
         st = window_stats(rec)
         due = len(st["ttft"])
         print(json.dumps({
@@ -518,7 +536,8 @@ def run_cell(args, bench_dir, require_tpu, overrides, fault) -> dict:
         trace_root = bench_dir.parent / ".bench_out" / "trace"
         shutil.rmtree(trace_root, ignore_errors=True)
     setup_s = time.perf_counter() - T_PROC0
-    rec = run_window(engine, reqs, cell, args.seconds, trace_root)
+    with under_mesh(s):
+        rec = run_window(engine, reqs, cell, args.seconds, trace_root)
     dev = device_info(s["devs"])
 
     checks = {}
@@ -532,7 +551,7 @@ def run_cell(args, bench_dir, require_tpu, overrides, fault) -> dict:
     metrics, breakdown = {}, None
     if args.trace:
         ctx = {"cell": cell, "conf": conf, "peaks": s["peaks"],
-               "dims": __import__("weights").dims(conf),
+               "dims": s["arch"].weights.dims(conf),
                "chips": cell["chips"]}
         red = reduce_traces(trace_root)
         shutil.rmtree(trace_root, ignore_errors=True)
@@ -564,7 +583,8 @@ def run_cell(args, bench_dir, require_tpu, overrides, fault) -> dict:
     for b, pk in picked.items():
         if not pk:
             continue
-        cmp = compare(s["params"], s["rp"], conf, cell, pk, b, args.control)
+        cmp = compare(s["arch"].reference.served_gaps, s["params"], s["rp"],
+                      conf, cell, pk, b, args.control)
         log(f"compared {cmp['requests']} requests at budget {b}, "
             f"{cmp['tokens']} served tokens in {cmp['seconds']:.1f} s: "
             f"{cmp['program']}")

@@ -95,12 +95,12 @@ def measure(s, workload: str, seed: int, seconds: float, bench_dir: Path,
             keep: Path | None = None) -> dict:
     """One traced window of the cell, reduced; the result line's object."""
     import traffic
-    import weights
     cell, conf, engine = s["cell"], s["conf"], s["engine"]
     reqs = traffic.generate(cell, seconds, seed, conf["vocab_size"])
     root = bench_dir.parent / ".bench_out" / "spans"
     shutil.rmtree(root, ignore_errors=True)
-    rec = run.run_window(engine, reqs, cell, seconds, root)
+    with run.under_mesh(s):
+        rec = run.run_window(engine, reqs, cell, seconds, root)
     reds, files = [], []
     for d in sorted(p for p in root.iterdir() if p.is_dir()):
         files.append(_one_file(d))
@@ -115,7 +115,7 @@ def measure(s, workload: str, seed: int, seconds: float, bench_dir: Path,
     shutil.rmtree(root, ignore_errors=True)
     red = E.combine(reds)
     ctx = {"cell": cell, "conf": conf, "peaks": s["peaks"],
-           "dims": weights.dims(conf), "chips": cell["chips"]}
+           "dims": s["arch"].weights.dims(conf), "chips": cell["chips"]}
     trec = run.traced_record(rec)
     layer = {}
     for m in run.per_layer_metrics(workload, bench_dir):
@@ -156,7 +156,9 @@ def main(argv=None, *, require_tpu: bool = True, bench_dir: Path = run.BENCH,
     args = run.parse(rest)
     s = run.setup(args, bench_dir, require_tpu, overrides, None)
     if own.record is not None:
-        for f in record(s, args.seed, args.seconds, own.record):
+        with run.under_mesh(s):
+            files = record(s, args.seed, args.seconds, own.record)
+        for f in files:
             print(json.dumps({"trace": str(f), "bytes": f.stat().st_size}),
                   flush=True)
         return 0

@@ -11,6 +11,10 @@ BENCH = Path(__file__).resolve().parents[1]
 SMALL = {"hidden_size": 64, "intermediate_size": 176,
          "num_attention_heads": 4, "num_key_value_heads": 2,
          "num_hidden_layers": 4, "vocab_size": 2048}
+# a configuration of a cell on 4 chips: as many kv heads as chips, so that
+# each chip holds one of them and two q heads, as qwen2-7b's one and seven
+SMALL_TP = dict(SMALL, hidden_size=128, num_attention_heads=8,
+                num_key_value_heads=4)
 
 
 def _budget_row(conf: dict, budget: float) -> dict:
@@ -42,12 +46,17 @@ def smoke_checkout(tmp: Path, rate: float = 8.0) -> Path:
     b = tmp / "bench"
     for sub in ("configs", "workloads"):
         (b / sub).mkdir(parents=True, exist_ok=True)
-    shutil.copytree(BENCH / "metrics", b / "metrics", dirs_exist_ok=True)
+    for sub in ("metrics", "archs"):
+        shutil.copytree(BENCH / sub, b / sub, dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(BENCH / "peaks.json", b / "peaks.json")
     shutil.copy(BENCH.parent / "BENCHMARK.json", tmp / "BENCHMARK.json")
+    spec = json.loads((tmp / "BENCHMARK.json").read_text())
+    tp = {w["config"] for w in spec["workloads"] if w["chips"] > 1}
+    tp_cells = {w["traffic"] for w in spec["workloads"] if w["chips"] > 1}
     for f in (BENCH / "configs").glob("*.json"):
         c = json.loads(f.read_text())
-        c.update(SMALL)
+        c.update(SMALL_TP if f.stem in tp else SMALL)
         c["engine"] = dict(c["engine"], slots=4, max_seq=96)
         for b_ in c["elastic"]["budgets"]:
             if c["elastic"]["budgets"][b_]["routed"]:
@@ -63,7 +72,9 @@ def smoke_checkout(tmp: Path, rate: float = 8.0) -> Path:
             if "output" in c:
                 c["output"] = dict(w["output"])
         if w["arrivals"]["kind"] == "offline":
-            w["arrivals"]["backlog"] = 64
+            # the four-chip cell's run is held to `correct`, which needs
+            # requests left at the close: a 2 s window finishes some 64
+            w["arrivals"]["backlog"] = 256 if f.stem in tp_cells else 64
         else:
             w["arrivals"]["rate"] = rate
         w["check"] = dict(w["check"], tokens=64, max_requests=8,

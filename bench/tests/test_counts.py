@@ -3,12 +3,13 @@ import json
 
 import pytest
 
+import archs
 import counts
 from conftest import BENCH
-from weights import dims
 
-D = dims(json.loads((BENCH / "configs" / "qwen2-7b-elastic-ring.json")
-                    .read_text()))
+CONF = json.loads((BENCH / "configs" / "qwen2-7b-elastic-ring.json")
+                  .read_text())
+D = archs.load(CONF, BENCH).weights.dims(CONF)
 PEAKS = json.loads((BENCH / "peaks.json").read_text())["TPU v5 lite"]
 
 

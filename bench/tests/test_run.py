@@ -19,6 +19,14 @@ from conftest import BENCH
 from smoke import smoke_checkout
 
 KEYS = ["correct", "attempted", "failed", "metrics", "device", "check"]
+CELLS = ["elastic-b05-chat", "paged-int8-rag-backlog", "elastic-b10-offline",
+         "tp4-b10-chat"]
+SEED = "3000000007"
+
+
+def chips(cell) -> int:
+    spec = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+    return {w["name"]: w["chips"] for w in spec["workloads"]}[cell]
 
 
 def gap_checks(res, cell):
@@ -29,20 +37,39 @@ def gap_checks(res, cell):
             if run.limit_key(k, budget)[0] in run.GAP_STATS]
 
 
+def multichip_run(tmp_path, cell, *extra, fault=None, backend="interpret"):
+    """The cell at the CPU size on four virtual devices, in a process of
+    its own (``multichip.py``): its result line and where the engine held
+    its state."""
+    cmd = [sys.executable, str(BENCH / "tests" / "multichip.py"),
+           str(tmp_path), "--workload", cell, "--seed", SEED, "--seconds",
+           "2", "--trace", "0", "--backend", backend, *extra]
+    if fault is not None:
+        cmd += ["--fault", fault.__name__]
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=str(BENCH.parent / "src"))
+    p = subprocess.run(cmd, env=env, capture_output=True, text=True,
+                       timeout=600)
+    assert p.returncode == 0, p.stderr[-4000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    return out["result"], out["placement"]
+
+
 def one_run(tmp_path, cell, *extra, fault=None, backend="interpret"):
+    if chips(cell) > 1:
+        return multichip_run(tmp_path, cell, *extra, fault=fault,
+                             backend=backend)[0]
     b = smoke_checkout(tmp_path)
     out = io.StringIO()
     with redirect_stdout(out):
-        run.main(["--workload", cell, "--seed", "3000000007", "--seconds",
+        run.main(["--workload", cell, "--seed", SEED, "--seconds",
                   "2", "--trace", "0", *extra], require_tpu=False,
                  bench_dir=b, overrides={"kernel_backend": backend},
                  fault=fault)
     return json.loads(out.getvalue().strip().splitlines()[-1])
 
 
-@pytest.mark.parametrize("cell", ["elastic-b05-chat",
-                                  "paged-int8-rag-backlog",
-                                  "elastic-b10-offline"])
+@pytest.mark.parametrize("cell", CELLS)
 def test_rehearsal_last_line(tmp_path, cell):
     res = one_run(tmp_path, cell)
     assert list(res) == KEYS
@@ -54,14 +81,28 @@ def test_rehearsal_last_line(tmp_path, cell):
     assert res["check"]["window_compiles"] == {"value": 0, "limit": 0}
     assert res["attempted"] > 0 and res["failed"] == 0
     assert res["device"]["platform"] == "cpu"
+    assert res["device"]["count"] == chips(cell)
+
+
+def test_multichip_cell_runs_on_the_mesh(tmp_path):
+    """A cell on four chips: every parameter, router and cache leaf is
+    placed over the whole (data=1, model=4) mesh, the weights and the KV
+    cache split over ``model``, and the run comes out correct."""
+    res, placed = multichip_run(tmp_path, "tp4-b10-chat", backend="ref")
+    assert placed["mesh"] == {"data": 1, "model": 4}
+    for tree in ("params", "rp", "caches"):
+        assert placed[tree]["leaves"] > 0
+        assert placed[tree]["on_mesh"] == placed[tree]["leaves"], tree
+    assert placed["params"]["split"] >= 12      # q/k/v/o, biases, MLP,
+    assert placed["caches"]["split"] >= 2       # embedding, head; K and V
+    assert res["correct"] is True, res["check"]
+    assert res["check"]["backlog_left"]["value"] >= 1
 
 
 @pytest.mark.parametrize("fault", [faults.token_altered,
                                    faults.state_unchanged],
                          ids=["token_altered", "state_unchanged"])
-@pytest.mark.parametrize("cell", ["elastic-b05-chat",
-                                  "paged-int8-rag-backlog",
-                                  "elastic-b10-offline"])
+@pytest.mark.parametrize("cell", CELLS)
 def test_fault_turns_correct_false(tmp_path, cell, fault):
     res = one_run(tmp_path, cell, fault=fault, backend="ref")
     assert any(c["value"] > c["limit"] for c in gap_checks(res, cell))

@@ -17,6 +17,7 @@ the stretches of one run.
 """
 from __future__ import annotations
 
+import bisect
 import re
 from collections import defaultdict
 
@@ -120,7 +121,9 @@ def reduce(pd, programs: dict, kernels=(), n_top: int = 10) -> dict:
 
     ``programs``: {key: jitted function name}, e.g. {"decode": "step",
     "admit": "admit"}; ``kernels``: Pallas kernel names to time. Device
-    numbers are averaged over the device planes (one per chip used)."""
+    numbers are averaged over the device planes (one per chip used).
+    ``program_op_s`` gives, for each program, the seconds of each operation
+    (by ``base_name``) that starts inside one of its executions."""
     p = planes(pd)
     if not p["device"]:
         raise TraceError("no TPU device plane in the trace")
@@ -133,20 +136,25 @@ def reduce(pd, programs: dict, kernels=(), n_top: int = 10) -> dict:
     hi = max(e[2] for e in p["host"])
     window = (hi - lo) / 1e9
     busy, prog_t, prog_n, kern_t, op_t, gaps = [], [], [], [], [], []
+    prog_op = []
     for _name, lines in p["device"]:
         ops = lines.get(OPS, [])
         mods = lines.get(MODULES, [])
         merged = union([(s, e) for _n, s, e in ops], lo, hi)
         busy.append(sum(e - s for s, e in merged) / 1e9)
-        pt, runs = defaultdict(float), defaultdict(set)
+        pt, runs, execs = defaultdict(float), defaultdict(set), []
         for i, (n, s, e, run) in enumerate(mods):
             key = program_of(n, programs)
             if key is not None and lo <= s and e <= hi:
                 pt[key] += (e - s) / 1e9
                 runs[key].add(run if run is not None else ("event", i))
+                execs.append((s, e, key))
         prog_t.append(pt)
         prog_n.append({k: len(v) for k, v in runs.items()})
+        execs.sort()
+        starts = [x[0] for x in execs]
         kt, ot = defaultdict(float), defaultdict(float)
+        pot = defaultdict(lambda: defaultdict(float))
         for n, s, e in ops:
             if not (lo <= s and e <= hi):
                 continue
@@ -154,8 +162,12 @@ def reduce(pd, programs: dict, kernels=(), n_top: int = 10) -> dict:
             ot[b] += (e - s) / 1e9
             if b in kernels:
                 kt[b] += (e - s) / 1e9
+            j = bisect.bisect_right(starts, s) - 1
+            if j >= 0 and s < execs[j][1] and b not in CONTAINERS:
+                pot[execs[j][2]][b] += (e - s) / 1e9
         kern_t.append(kt)
         op_t.append(ot)
+        prog_op.append(pot)
         edges = [lo] + [x for iv in merged for x in iv] + [hi]
         gaps += [(edges[i], edges[i + 1])
                  for i in range(0, len(edges), 2) if edges[i + 1] > edges[i]]
@@ -171,7 +183,9 @@ def reduce(pd, programs: dict, kernels=(), n_top: int = 10) -> dict:
         "window_s": window,
         "busy_s": sum(busy) / nd,
         "program_s": mean(prog_t),
-        "program_n": {k: v / nd for k, v in mean(prog_n).items()},
+        "program_n": mean(prog_n),
+        "program_op_s": {k: mean([p.get(k, {}) for p in prog_op])
+                         for k in set().union(*prog_op)},
         "kernel_s": mean(kern_t),
         "op_s": ops_mean,
         "device_ops": sorted(ops_mean.items(), key=lambda kv: -kv[1])[:n_top],
@@ -191,6 +205,11 @@ def combine(reds, n_top: int = 10) -> dict:
                 out[k] += v
         return dict(out)
     ops = total("op_s")
+    prog_op = defaultdict(lambda: defaultdict(float))
+    for r in reds:
+        for prog, d in r["program_op_s"].items():
+            for k, v in d.items():
+                prog_op[prog][k] += v
     gaps = sorted((g for r in reds for g in r["idle_gaps"]),
                   key=lambda g: -g[1])
     return {
@@ -198,6 +217,7 @@ def combine(reds, n_top: int = 10) -> dict:
         "busy_s": sum(r["busy_s"] for r in reds),
         "program_s": total("program_s"),
         "program_n": total("program_n"),
+        "program_op_s": {k: dict(v) for k, v in prog_op.items()},
         "kernel_s": total("kernel_s"),
         "op_s": ops,
         "device_ops": sorted(ops.items(), key=lambda kv: -kv[1])[:n_top],
