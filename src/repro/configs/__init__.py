@@ -8,7 +8,7 @@ from repro.configs.base import (
 from repro.configs import (  # noqa: F401
     phi3_medium_14b, gemma3_27b, qwen2_7b, granite_34b, mamba2_780m,
     qwen2_moe_a2p7b, grok1_314b, recurrentgemma_2b, whisper_medium,
-    llama32_vision_11b, elasti_toy,
+    llama32_vision_11b, elasti_toy, lfm2_8b_a1b,
 )
 
 ASSIGNED = [

@@ -17,14 +17,23 @@ def _round_up(x: int, m: int) -> int:
 
 @dataclass(frozen=True)
 class MoEConfig:
-    """Native mixture-of-experts MLP config (qwen2-moe, grok-1)."""
+    """Native mixture-of-experts MLP config (qwen2-moe, grok-1, lfm2-moe).
+
+    Native experts are dispatched dropless: every token reaches each of its
+    top-k experts, in prefill as in decode.
+
+    ``router`` "softmax": weights ``softmax(h W_r)``, the top-k by weight.
+    ``router`` "sigmoid" (lfm2-moe): scores ``s = sigmoid(h W_r)``; the
+    experts are the top-k of ``s + expert_bias``, each weighted
+    ``s_e / (sum of the chosen s + 1e-6)``: the bias picks the experts but
+    never weights them."""
     n_experts: int
     top_k: int
     d_expert: int                  # ffn dim per expert
     n_shared_experts: int = 0      # qwen2-moe: shared (always-on) experts
     d_shared: int = 0              # ffn dim of the shared expert path
-    capacity_factor: float = 1.25  # dispatch buffer slack (training)
     seq_chunk: int = 2048          # dispatch seq chunking to bound buffers
+    router: str = "softmax"        # softmax | sigmoid
 
 
 @dataclass(frozen=True)
@@ -36,7 +45,13 @@ class ModelConfig:
       'ssm'    - Mamba2 SSD block
       'rglru'  - RecurrentGemma RG-LRU block
       'xattn'  - self attention + cross attention (enc-dec decoder / VLM layer)
+      'conv'   - LFM2 gated short convolution
     Layers beyond the last full period reuse the pattern prefix.
+    ``layer_types``, when given, lists every layer's kind instead (a
+    published layout that no period tiles). Either way the layer stack
+    scans the period that repeats most after the leading dense layers.
+    ``n_dense_layers``: with ``moe``, the first layers keep a dense MLP of
+    width ``d_ff``.
     """
     name: str
     family: str                     # dense | moe | ssm | hybrid | encdec | vlm
@@ -48,8 +63,10 @@ class ModelConfig:
     vocab_size: int
     d_head: int = 128
     norm: str = "rmsnorm"           # rmsnorm | layernorm
+    norm_eps: float = 1e-6
     act: str = "swiglu"             # swiglu | geglu | gelu
     qkv_bias: bool = False
+    qk_norm: bool = False           # RMSNorm over head_dim on q, k before RoPE
     tie_embeddings: bool = False
     eos_id: Optional[int] = None    # stop token; serving default for requests
     rope_theta: float = 10_000.0
@@ -58,8 +75,10 @@ class ModelConfig:
     # e.g. gemma3: (1024,1024,1024,1024,1024,0) -> 5 local : 1 global.
     window_pattern: Tuple[int, ...] = (0,)
     mixer_pattern: Tuple[str, ...] = ("attn",)
+    layer_types: Tuple[str, ...] = ()
     # MoE
     moe: Optional[MoEConfig] = None
+    n_dense_layers: int = 0
     # SSM (mamba2)
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -83,8 +102,17 @@ class ModelConfig:
 
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
+        if self.layer_types:
+            assert len(self.layer_types) >= self.n_layers, self.layer_types
+            return tuple(self.layer_types[:self.n_layers])
         p = self.mixer_pattern
         return tuple(p[i % len(p)] for i in range(self.n_layers))
+
+    @property
+    def layer_moe(self) -> Tuple[bool, ...]:
+        """Per layer: True where its MLP is the native MoE."""
+        return tuple(self.moe is not None and i >= self.n_dense_layers
+                     for i in range(self.n_layers))
 
     @property
     def layer_windows(self) -> Tuple[int, ...]:
@@ -117,14 +145,20 @@ class ModelConfig:
         if self.lru_width:
             w = self.lru_width
             per_kind["rglru"] = D * 2 * w + w * D + 2 * w * w + self.conv_kernel * w
+        per_kind["conv"] = 4 * D * D + self.conv_kernel * D
+        if self.qk_norm:
+            per_kind["attn"] += 2 * self.d_head
+        n_dense = (3 if self.act in ("swiglu", "geglu") else 2) * D * F
+        n_moe = 0
         if self.moe is not None:
             m = self.moe
-            n_mlp = m.n_experts * 3 * D * m.d_expert + D * m.n_experts
+            n_moe = m.n_experts * 3 * D * m.d_expert + D * m.n_experts
             if m.n_shared_experts:
-                n_mlp += 3 * D * m.d_shared
-        else:
-            n_mlp = (3 if self.act in ("swiglu", "geglu") else 2) * D * F
-        for k in self.layer_kinds:
+                n_moe += 3 * D * m.d_shared
+            if m.router == "sigmoid":
+                n_moe += m.n_experts  # expert_bias
+        for k, is_moe in zip(self.layer_kinds, self.layer_moe):
+            n_mlp = n_moe if is_moe else n_dense
             n += per_kind.get(k, per_kind.get("attn", 0)) + (n_mlp if k != "ssm" else 0)
             n += 2 * D  # norms
         if self.encoder is not None:
@@ -139,7 +173,7 @@ class ModelConfig:
         D = self.d_model
         full_moe = m.n_experts * 3 * D * m.d_expert
         act_moe = m.top_k * 3 * D * m.d_expert
-        return self.n_params() - len(self.layer_kinds) * (full_moe - act_moe)
+        return self.n_params() - sum(self.layer_moe) * (full_moe - act_moe)
 
 
 @dataclass(frozen=True)

@@ -386,8 +386,12 @@ def stack_flops_per_token(cfg, spec: ElasticSpec, *, ctx: int = 1024):
             c_lru = 2 * D * 2 * w + 2 * w * D + 2 * 2 * w * w
             (mixer, fixed) = (mixer + c_lru, fixed) if elastic_l \
                 else (mixer, fixed + c_lru)
+        elif kind == "conv":
+            c_conv = 2 * D * 3 * D + 2 * D * D + 2 * cfg.conv_kernel * D
+            (mixer, fixed) = (mixer + c_conv, fixed) if elastic_l \
+                else (mixer, fixed + c_conv)
         if kind != "ssm":
-            if cfg.moe is not None:
+            if cfg.layer_moe[i]:
                 m = cfg.moe
                 c_mlp = m.top_k * n_gate * 2 * D * m.d_expert
                 if m.n_shared_experts:
@@ -401,6 +405,12 @@ def stack_flops_per_token(cfg, spec: ElasticSpec, *, ctx: int = 1024):
     routed = {"attn_head": attn_head, "attn_kv": attn_kv,
               "mlp": mlp, "mixer": mixer}
     return fixed, routed
+
+
+def _expert_count(cfg, spec: ElasticSpec):
+    """What the elastic expert knob counts: the native MoE's top-k (the
+    knob keeps the top of the router's choice), else the moefied experts."""
+    return cfg.moe.top_k if cfg.moe is not None else spec.mlp_n_experts
 
 
 def _active_fraction(cfg, spec: ElasticSpec, s: float, *, ctx: int) -> float:
@@ -418,7 +428,7 @@ def _active_fraction(cfg, spec: ElasticSpec, s: float, *, ctx: int) -> float:
         frac_head = max(1, math.ceil(s * cfg.n_heads - 1e-9)) / cfg.n_heads
     frac_exp = 1.0
     if spec.expert_routed:
-        n_e = cfg.moe.n_experts if cfg.moe is not None else spec.mlp_n_experts
+        n_e = _expert_count(cfg, spec)
         if n_e:
             frac_exp = max(1, math.ceil(s * n_e - 1e-9)) / n_e
     active = (fixed
@@ -448,7 +458,7 @@ def solve_budget(cfg, spec: ElasticSpec, budget: float, *, ctx: int = 1024,
         else:
             lo = mid
     s = 0.5 * (lo + hi)
-    n_e = cfg.moe.n_experts if cfg.moe is not None else spec.mlp_n_experts
+    n_e = _expert_count(cfg, spec)
     return ElasticPolicy.uniform(
         s, n_heads=cfg.n_heads if spec.mha_head_routed else None,
         n_experts=n_e if spec.expert_routed else None,

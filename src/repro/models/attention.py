@@ -23,7 +23,8 @@ import jax.numpy as jnp
 from repro.core.lora import lora_apply
 from repro.kernels import ops as OPS
 from repro.models import quant
-from repro.models.layers import dense_init, dtype_of, rope_apply, rope_tables
+from repro.models.layers import (dense_init, dtype_of, norm_apply,
+                                 norm_init, rope_apply, rope_tables)
 from repro.runtime import sharding as SH
 
 NEG_INF = -1e30
@@ -65,6 +66,9 @@ def attn_init(key, cfg, cross: bool = False):
         p["bq"] = jnp.zeros((H, Dh), dt)
         p["bk"] = jnp.zeros((K, Dh), dt)
         p["bv"] = jnp.zeros((K, Dh), dt)
+    if cfg.qk_norm:
+        p["q_norm"] = norm_init(Dh, "rmsnorm")
+        p["k_norm"] = norm_init(Dh, "rmsnorm")
     return p
 
 
@@ -90,6 +94,8 @@ def _project_q(p, x, positions, cfg, lora, use_rope):
         q = q + dq
     if "bq" in p:
         q = q + p["bq"]
+    if "q_norm" in p:   # qk-norm: RMSNorm over head_dim, before RoPE
+        q = norm_apply(p["q_norm"], q, "rmsnorm", cfg.norm_eps)
     if use_rope:
         cos, sin = rope_tables(positions, cfg.d_head, cfg.rope_theta)
         if cos.ndim == 2:  # (S, half) -> broadcast over batch
@@ -112,6 +118,8 @@ def _project_kv(p, x, positions, cfg, lora, use_rope):
         v = v + dv
     if "bk" in p:
         k, v = k + p["bk"], v + p["bv"]
+    if "k_norm" in p:
+        k = norm_apply(p["k_norm"], k, "rmsnorm", cfg.norm_eps)
     if use_rope:
         cos, sin = rope_tables(positions, cfg.d_head, cfg.rope_theta)
         if cos.ndim == 2:

@@ -5,6 +5,11 @@ Block kinds (cfg.mixer_pattern):
   xattn : same + cross-attention to encoder/image context       + MLP block
   ssm   : [token-route] Mamba2 SSD mixer (no MLP)
   rglru : [token-route] RG-LRU recurrent mixer                  + MLP block
+  conv  : [token-route] LFM2 gated short convolution            + MLP block
+
+The MLP block is dense, moefied (ElastiFormer experts over the dense MLP)
+or the native MoE; a layer's params say which (a native MoE holds
+``router``), so leading dense layers of a MoE model need no flag.
 
 Elasticity is split into a static ``ElasticSpec`` (which routers exist —
 shapes params and HLO) and a runtime ``ElasticPolicy`` (capacities, head/
@@ -25,9 +30,10 @@ Token routing semantics per mixer family:
   attention : top-k tokens attend among themselves (MoD semantics) — the
               ragged/gather paths deliver real FLOP savings in the lowered
               HLO; the masked path computes the same math at full shapes.
-  ssm/rglru : skipped tokens leave the recurrent state untouched (dt=0 /
-              a=1 exact pass-through); dense-masked in both train and infer
-              so train/infer semantics coincide.
+  ssm/rglru/conv : skipped tokens leave the recurrent state untouched
+              (dt=0 / a=1 exact pass-through; conv: a skipped token never
+              enters the convolution's history); dense-masked in both
+              train and infer so train/infer semantics coincide.
 
 Routed execution (this PR's hot path): train-mode top-k selection is
 planned ONCE per block (core/routing.RoutingPlan — one sort, shared by the
@@ -53,6 +59,7 @@ from repro.core.lora import lora_init
 from repro.models import attention as A
 from repro.models import quant
 from repro.models import rglru as G
+from repro.models import shortconv as SC
 from repro.models import ssm as S
 from repro.models.layers import mlp_apply, mlp_init, norm_apply, norm_init
 from repro.models.moe import moe_apply, moe_decode, moe_init
@@ -68,7 +75,13 @@ def is_attn(kind: str) -> bool:
 
 # ------------------------------ init ---------------------------------------
 
-def block_init(key, kind: str, cfg):
+def is_moe(p) -> bool:
+    """Whether a layer's MLP params are a native MoE."""
+    return "router" in p["mlp"]
+
+
+def block_init(key, kind: str, cfg, *, moe: bool):
+    """One layer's params; ``moe``: its MLP is the native MoE."""
     ks = jax.random.split(key, 6)
     p = {"norm1": norm_init(cfg.d_model, cfg.norm)}
     if is_attn(kind):
@@ -77,6 +90,8 @@ def block_init(key, kind: str, cfg):
         p["mixer"] = S.ssm_init(ks[0], cfg)
     elif kind == "rglru":
         p["mixer"] = G.rglru_init(ks[0], cfg)
+    elif kind == "conv":
+        p["mixer"] = SC.conv_init(ks[0], cfg)
     else:
         raise ValueError(kind)
     if kind == "xattn":
@@ -84,13 +99,15 @@ def block_init(key, kind: str, cfg):
         p["xattn"] = A.attn_init(ks[1], cfg, cross=True)
     if has_mlp(kind):
         p["norm2"] = norm_init(cfg.d_model, cfg.norm)
-        p["mlp"] = moe_init(ks[2], cfg) if cfg.moe is not None else mlp_init(ks[2], cfg)
+        p["mlp"] = moe_init(ks[2], cfg) if moe else mlp_init(ks[2], cfg)
     return p
 
 
-def block_router_init(key, kind: str, cfg, spec):
+def block_router_init(key, kind: str, cfg, spec, *, moe: bool):
     """Trainable ElastiFormer params for one layer (tiny; see Table 1).
-    ``spec`` is the static ElasticSpec: it alone decides which routers exist."""
+    ``spec`` is the static ElasticSpec: it alone decides which routers exist.
+    A native MoE layer (``moe``) has no expert router of its own: the
+    elastic expert knob keeps the top of its native router's choice."""
     D = cfg.d_model
     ks = jax.random.split(key, 6)
     rp = {}
@@ -114,9 +131,8 @@ def block_router_init(key, kind: str, cfg, spec):
     if has_mlp(kind):
         if spec.mlp_token_routed:
             rp["tok_mlp"] = R.token_router_init(ks[4], D)
-        n_exp = cfg.moe.n_experts if cfg.moe is not None else spec.mlp_n_experts
-        if n_exp and spec.expert_routed:
-            rp["expert"] = R.param_router_init(ks[5], D, n_exp)
+        if spec.mlp_n_experts and spec.expert_routed and not moe:
+            rp["expert"] = R.param_router_init(ks[5], D, spec.mlp_n_experts)
     return rp
 
 
@@ -178,23 +194,18 @@ def _mlp_fn(p, rp, cfg, spec, pol, elastic_on, mode, auxes, backend=None):
     through ``kernels.ops.moe_gmm``."""
     @jax.named_scope("mlp")
     def f(h, _pos, token_valid=None, dispatch_frac=None, token_count=None):
-        if cfg.moe is not None:
-            if elastic_on and rp and "expert" in rp and mode != "base":
-                y, a = moe_apply(
-                    p["mlp"], h, act=cfg.act,
-                    router_w=rp["expert"]["w"], normalize_to_m=True,
-                    capacity_factor=cfg.moe.capacity_factor,
-                    seq_chunk=cfg.moe.seq_chunk, token_valid=token_valid,
-                    dispatch_frac=dispatch_frac, token_count=token_count,
-                    backend=backend,
-                    **_expert_args(pol, cfg.moe.n_experts))
-            else:
-                y, a = moe_apply(
-                    p["mlp"], h, act=cfg.act, top_k=cfg.moe.top_k,
-                    capacity_factor=cfg.moe.capacity_factor,
-                    seq_chunk=cfg.moe.seq_chunk, token_valid=token_valid,
-                    dispatch_frac=dispatch_frac, token_count=token_count,
-                    backend=backend)
+        if is_moe(p):
+            # native experts: dropless dispatch, the native router; the
+            # elastic expert knob keeps the top of its top-k
+            m = cfg.moe
+            kn = (_expert_args(pol, m.top_k)
+                  if elastic_on and mode != "base" and spec is not None
+                  and spec.expert_routed else {"top_k": m.top_k})
+            y, a = moe_apply(
+                p["mlp"], h, act=cfg.act, seq_chunk=m.seq_chunk,
+                token_valid=token_valid, dispatch_frac=dispatch_frac,
+                token_count=token_count, backend=backend, router=m.router,
+                **kn)
             auxes.append(a)
             return y
         if (elastic_on and rp and "expert" in rp and mode != "base"
@@ -414,7 +425,7 @@ def block_apply(
         return keep, wtok
 
     # ---- temporal mixer ----
-    h = norm_apply(p["norm1"], x, cfg.norm)
+    h = norm_apply(p["norm1"], x, cfg.norm, cfg.norm_eps)
     dense_keep = None               # shared keep of the dense fallback
 
     if is_attn(kind):
@@ -488,7 +499,7 @@ def block_apply(
             cache["attn"] = _pad_cache(
                 k, v, keep, L, window,
                 kv_dtype=spec.kv_dtype if spec is not None else "fp32")
-    else:  # ssm / rglru — dense masked routing (state pass-through semantics)
+    else:  # ssm / rglru / conv — dense masked routing (state pass-through)
         keep = None
         if mixer_routers:
             if identity:
@@ -519,6 +530,10 @@ def block_apply(
             y, (st, cv) = S.ssm_apply(p["mixer"], h, cfg, keep_mask=keep)
             if collect_cache:
                 cache["ssm"] = {"state": st, "conv": cv}
+        elif kind == "conv":
+            y, st = SC.conv_apply(p["mixer"], h, cfg, keep_mask=keep)
+            if collect_cache:
+                cache["conv"] = st
         else:
             y, (st, cv) = G.rglru_apply(p["mixer"], h, cfg, keep_mask=keep)
             if collect_cache:
@@ -531,7 +546,7 @@ def block_apply(
 
     # ---- cross attention (xattn) ----
     if kind == "xattn":
-        hx = norm_apply(p["xnorm"], x, cfg.norm)
+        hx = norm_apply(p["xnorm"], x, cfg.norm, cfg.norm_eps)
         lora = None
         y, xk, xv = A.attn_apply(
             p["xattn"], hx, cfg=cfg, positions=positions, causal=False,
@@ -545,7 +560,7 @@ def block_apply(
 
     # ---- MLP ----
     if has_mlp(kind):
-        h = norm_apply(p["norm2"], x, cfg.norm)
+        h = norm_apply(p["norm2"], x, cfg.norm, cfg.norm_eps)
         f = _mlp_fn(p, rp, cfg, spec, pol, elastic_on, mode, auxes,
                     backend=backend)
         if cap_mlp is None and cap_depth is None:
@@ -719,7 +734,7 @@ def block_decode(kind: str, p, rp, x, cache, t, *, cfg, spec, pol=None,
         spec.kernel_backend if spec is not None else None)
     new_cache = dict(cache)
 
-    h = norm_apply(p["norm1"], x, cfg.norm)
+    h = norm_apply(p["norm1"], x, cfg.norm, cfg.norm_eps)
     keepd, wd = None, None
     if routed and spec.depth_routed and "depth" in rp:
         # per-(slot, layer) whole-layer skip: the token writes NO KV at
@@ -759,6 +774,9 @@ def block_decode(kind: str, p, rp, x, cache, t, *, cfg, spec, pol=None,
     elif kind == "ssm":
         y, new_cache["ssm"] = S.ssm_decode(p["mixer"], h, cache["ssm"], cfg,
                                            write=keep)
+    elif kind == "conv":
+        y, new_cache["conv"] = SC.conv_decode(p["mixer"], h, cache["conv"],
+                                              cfg, write=keep)
     else:
         y, new_cache["rglru"] = G.rglru_decode(p["mixer"], h, cache["rglru"],
                                                cfg, write=keep)
@@ -767,7 +785,7 @@ def block_decode(kind: str, p, rp, x, cache, t, *, cfg, spec, pol=None,
     x = x + y
 
     if kind == "xattn":
-        hx = norm_apply(p["xnorm"], x, cfg.norm)
+        hx = norm_apply(p["xnorm"], x, cfg.norm, cfg.norm_eps)
         xc = cache["xattn"]
         pos = jnp.zeros((B, 1), jnp.int32)
         kvp = jnp.broadcast_to(jnp.arange(xc["k"].shape[1], dtype=jnp.int32),
@@ -781,7 +799,7 @@ def block_decode(kind: str, p, rp, x, cache, t, *, cfg, spec, pol=None,
         x = x + y
 
     if has_mlp(kind):
-        h = norm_apply(p["norm2"], x, cfg.norm)
+        h = norm_apply(p["norm2"], x, cfg.norm, cfg.norm_eps)
         keep2, w2 = None, None
         if routed and spec.mlp_token_routed and "tok_mlp" in rp:
             keep2, w2 = _decode_token_gate(rp, "tok_mlp", h,
@@ -790,15 +808,12 @@ def block_decode(kind: str, p, rp, x, cache, t, *, cfg, spec, pol=None,
             keep2 = keepd if keep2 is None else keep2 & keepd
             w2 = wd if w2 is None else w2 * wd
         with jax.named_scope("mlp"):
-            if cfg.moe is not None:
-                if routed and "expert" in rp:
-                    y, _ = moe_decode(p["mlp"], h, act=cfg.act,
-                                      router_w=rp["expert"]["w"],
-                                      normalize_to_m=True,
-                                      **_expert_args(pol, cfg.moe.n_experts))
-                else:
-                    y, _ = moe_decode(p["mlp"], h, act=cfg.act,
-                                      top_k=cfg.moe.top_k)
+            if is_moe(p):
+                m = cfg.moe
+                kn = (_expert_args(pol, m.top_k)
+                      if routed and spec.expert_routed else {"top_k": m.top_k})
+                y, _ = moe_decode(p["mlp"], h, act=cfg.act, router=m.router,
+                                  **kn)
             elif routed and "expert" in rp and spec.mlp_n_experts:
                 ep = moefy_mlp(p["mlp"], spec.mlp_n_experts)
                 y, _ = moe_decode(ep, h, act=cfg.act,
@@ -826,8 +841,8 @@ def block_chunk(kind: str, p, rp, x, cache, write_page, table_row, pos0,
     attends through ``table_row`` (see attention.attn_chunk). pos0 / plen /
     write_page / table_row are traced, so ONE compile serves every chunk of
     every prompt length. Paged serving is attention-only with dense MLPs
-    (engine-validated): ``moe_apply``'s expert-capacity buffers are sized
-    by the sequence chunking, so expert dispatch is the one sub-block
+    (engine-validated): the moefied experts' capacity buffers are sized
+    by the sequence chunking, so their dispatch is the one sub-block
     whose one-shot and chunked results can drop different tokens.
     Returns (x', new_cache)."""
     assert mode != "train", "block_chunk is a serving (infer/base) path"
@@ -853,7 +868,7 @@ def block_chunk(kind: str, p, rp, x, cache, write_page, table_row, pos0,
             cap_mlp = R.gate_capacity(pol.mlp_token_capacity, pol.student)
 
     # ---- attention (paged page write + table attend) ----
-    h = norm_apply(p["norm1"], x, cfg.norm)
+    h = norm_apply(p["norm1"], x, cfg.norm, cfg.norm_eps)
     lora = rp.get("lora") if routed else None
     lora = _lora_gate(lora, _mul_caps(cap_mha, cap_depth),
                       pol.student if (routed and pol is not None) else None)
@@ -885,7 +900,7 @@ def block_chunk(kind: str, p, rp, x, cache, write_page, table_row, pos0,
 
     # ---- MLP (dense; per-token threshold routing) ----
     if has_mlp(kind):
-        h = norm_apply(p["norm2"], x, cfg.norm)
+        h = norm_apply(p["norm2"], x, cfg.norm, cfg.norm_eps)
         f = _mlp_fn(p, rp, cfg, spec, pol, elastic_on, mode, auxes,
                     backend=backend)
         if cap_mlp is None:
@@ -945,4 +960,6 @@ def block_cache_init(kind: str, cfg, batch: int, max_seq: int, enc_len: int = 0,
         c["ssm"] = S.ssm_cache_init(cfg, batch)
     if kind == "rglru":
         c["rglru"] = G.rglru_cache_init(cfg, batch)
+    if kind == "conv":
+        c["conv"] = SC.conv_cache_init(cfg, batch)
     return c

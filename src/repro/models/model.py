@@ -42,6 +42,16 @@ class PatternPos(NamedTuple):
     kind: str
     window: int
     elastic: bool
+    moe: bool = False          # the layer's MLP is the native MoE
+
+
+class Pattern(NamedTuple):
+    """The layer stack's layout: ``lead`` layers run unrolled, then
+    ``period`` is scanned ``P`` times, then ``tail`` runs unrolled."""
+    lead: tuple
+    period: tuple
+    P: int
+    tail: tuple
 
 
 def _total(mesh, axes) -> int:
@@ -52,31 +62,54 @@ def _total(mesh, axes) -> int:
     return n
 
 
-def build_pattern(cfg, elastic=None):
-    """Returns (period: tuple[PatternPos], P, R). ``elastic`` is an
-    ElasticSpec or a legacy ElasticConfig (only .layers matters here)."""
+def _best_period(ents) -> tuple:
+    """(period length, repeats) of the period that, repeated from the
+    start, covers the most of ``ents`` (the shortest among equals); the
+    whole list once when nothing repeats."""
+    m = len(ents)
+    best, covered = (m, 1), 0
+    for p in range(1, m // 2 + 1):
+        r = 1
+        while (r + 1) * p <= m and ents[r * p:(r + 1) * p] == ents[:p]:
+            r += 1
+        if r >= 2 and p * r > covered:
+            best, covered = (p, r), p * r
+    return best
+
+
+def build_pattern(cfg, elastic=None) -> Pattern:
+    """The stack's ``Pattern``. ``elastic`` is an ElasticSpec or a legacy
+    ElasticConfig (only .layers matters here). The leading dense layers of
+    a MoE model (``cfg.n_dense_layers``) form ``lead``; the period is the
+    one that repeats most after ``lead``, over each layer's kind, window,
+    elastic flag and MLP kind; layers past its last repeat form ``tail``,
+    each with its own kind."""
     n = cfg.n_layers
-    base = math.lcm(len(cfg.mixer_pattern), len(cfg.window_pattern))
-    if elastic is not None and elastic.layers == "even":
-        base = math.lcm(base, 2)
-    period_len = base if base <= n else n
-    kinds, wins = cfg.layer_kinds, cfg.layer_windows
+    kinds, wins, moes = cfg.layer_kinds, cfg.layer_windows, cfg.layer_moe
     applies = (lambda i: True) if elastic is None else elastic.applies_to_layer
-    period = tuple(PatternPos(kinds[j], wins[j], applies(j))
-                   for j in range(period_len))
-    return period, n // period_len, n % period_len
+    ents = [PatternPos(kinds[i], wins[i], applies(i), moes[i])
+            for i in range(n)]
+    lead = min(cfg.n_dense_layers, n) if cfg.moe is not None else 0
+    rest = ents[lead:]
+    period_len, P = _best_period(rest) if rest else (0, 0)
+    return Pattern(tuple(ents[:lead]), tuple(rest[:period_len]), P,
+                   tuple(rest[period_len * P:]))
 
 
-def _split_layers(per_layer: list, period_len: int, P: int):
-    """[L trees] -> (scan: [period_len stacked-over-P trees], tail: [R trees])."""
+def _split_layers(per_layer: list, pat: Pattern) -> dict:
+    """[L trees] -> {"lead": [trees] (only when the pattern has a lead),
+    "scan": [period_len trees stacked over P], "tail": [trees]}."""
+    nl, np_ = len(pat.lead), len(pat.period)
     scan = []
-    for j in range(period_len):
-        if P > 0:
+    for j in range(np_):
+        if pat.P > 0:
             scan.append(jax.tree.map(
                 lambda *xs: jnp.stack(xs),
-                *[per_layer[p * period_len + j] for p in range(P)]))
-    tail = per_layer[P * period_len:]
-    return scan, tail
+                *[per_layer[nl + p * np_ + j] for p in range(pat.P)]))
+    out = {"scan": scan, "tail": per_layer[nl + np_ * pat.P:]}
+    if nl:
+        out["lead"] = per_layer[:nl]
+    return out
 
 
 # --------------------------- policy threading --------------------------------
@@ -89,36 +122,32 @@ def _pol_static(pol) -> bool:
     return pol is None or all(is_static(l) for l in jax.tree.leaves(pol))
 
 
-def _split_policy(pol, n_layers: int, period_len: int, P: int):
+def _split_policy(pol, n_layers: int, pat: Pattern) -> dict:
     """Per-layer split of a traced policy with (L, ...) leaves, mirroring
-    the parameter stacking. Returns (scan list, tail list)."""
-    per = [pol.for_layer(i) for i in range(n_layers)]
-    return _split_layers(per, period_len, P)
+    the parameter stacking (``_split_layers``)."""
+    return _split_layers([pol.for_layer(i) for i in range(n_layers)], pat)
 
 
-def _tail_plan(params, rparams, period, pol_tail, *, has_rp: bool,
-               static_pol: bool, pol):
-    """Hoisted per-tail-layer (params, entry, router-params, policy) tuples.
-
-    The tail loops used to re-derive ``period[i % len(period)]`` and the
-    per-layer policy selection inside every iteration of every trace; with
-    layered (L, B) policy leaves (per-layer depth schedules) that costs an
-    extra ``for_layer`` gather per layer per trace. Resolve once, zip in
-    the caller — the same hoist ``_split_policy`` does for the scan body.
-    ``pol_tail`` is the layered split (None when the policy has no layer
-    dim)."""
-    n = len(params["tail"])
-    ents = [period[i % len(period)] for i in range(n)]
-    rps = rparams["tail"] if has_rp else [None] * n
-    pols = list(pol_tail) if pol_tail is not None else \
+def _unrolled(part: str, params, rparams, pat: Pattern, pol_split, *,
+              has_rp: bool, static_pol: bool, pol):
+    """Hoisted (params, entry, router-params, policy) tuples of the
+    ``lead`` or ``tail`` layers, resolved once per trace (with layered
+    (L, B) policy leaves a per-iteration ``for_layer`` gather would cost
+    one per layer per trace). ``pol_split`` is ``_split_policy``'s result
+    (None when the policy has no layer dim)."""
+    lps = params.get(part, [])
+    n = len(lps)
+    ents = pat.lead if part == "lead" else pat.tail
+    rps = rparams.get(part, []) if has_rp else [None] * n
+    pols = list(pol_split.get(part, [])) if pol_split is not None else \
         [None if static_pol else pol] * n
-    return list(zip(params["tail"], ents, rps, pols))
+    return list(zip(lps, ents, rps, pols))
 
 
 # ------------------------------- init ---------------------------------------
 
 def model_init(key, cfg, elastic=None):
-    period, P, _ = build_pattern(cfg, elastic)
+    pat = build_pattern(cfg, elastic)
     dt = dtype_of(cfg)
     D, V = cfg.d_model, cfg.padded_vocab
     ks = jax.random.split(key, 8)
@@ -127,9 +156,10 @@ def model_init(key, cfg, elastic=None):
         params["embed"] = dense_init(ks[0], V, D, dt, scale=0.02)
         if not cfg.tie_embeddings:
             params["lm_head"] = dense_init(ks[1], D, V, dt)
-    layers = [block_init(jax.random.fold_in(ks[2], i), cfg.layer_kinds[i], cfg)
+    layers = [block_init(jax.random.fold_in(ks[2], i), cfg.layer_kinds[i], cfg,
+                         moe=cfg.layer_moe[i])
               for i in range(cfg.n_layers)]
-    params["scan"], params["tail"] = _split_layers(layers, len(period), P)
+    params.update(_split_layers(layers, pat))
     if cfg.family in ("encoder", "vlm") or cfg.d_frontend:
         params["in_proj"] = dense_init(ks[3], cfg.d_frontend or D, D, dt)
     if cfg.encoder is not None:
@@ -144,13 +174,13 @@ def router_init(key, cfg, elastic):
     """Trainable ElastiFormer parameter tree (mirrors the layer stacking).
     ``elastic``: ElasticSpec or legacy ElasticConfig."""
     spec, _ = as_spec_policy(elastic)
-    period, P, _ = build_pattern(cfg, spec)
+    pat = build_pattern(cfg, spec)
     ks = jax.random.split(key, 4)
     layers = [block_router_init(jax.random.fold_in(ks[0], i),
-                                cfg.layer_kinds[i], cfg, spec)
+                                cfg.layer_kinds[i], cfg, spec,
+                                moe=cfg.layer_moe[i])
               for i in range(cfg.n_layers)]
-    rp = {}
-    rp["scan"], rp["tail"] = _split_layers(layers, len(period), P)
+    rp = _split_layers(layers, pat)
     if spec.vlm_routed and (
             cfg.family in ("vlm", "encdec") or cfg.n_image_tokens):
         D = cfg.d_model
@@ -214,15 +244,13 @@ def select_context_tokens(rp, emb, spec, pol, mode: str):
 
 # ------------------------------ stack runner ---------------------------------
 
-def _run_stack(params, rparams, x, *, cfg, spec, pol, mode, period, causal,
+def _run_stack(params, rparams, x, *, cfg, spec, pol, mode, pat, causal,
                enc_kv=None, enc_valid=None, remat=False, bucket=None):
-    aux0 = RouteAux.zero()
+    aux = RouteAux.zero()
+    period = pat.period
     static_pol = _pol_static(pol)
     layered = (not static_pol) and pol.has_layer_dim
-    n_period, P_ = len(period), (cfg.n_layers // len(period))
-    pol_scan = pol_tail = None
-    if layered:
-        pol_scan, pol_tail = _split_policy(pol, cfg.n_layers, n_period, P_)
+    pol_split = _split_policy(pol, cfg.n_layers, pat) if layered else None
 
     def apply_block(ent, lp, lrp, lpol, x, enc_kv, enc_valid):
         return block_apply(
@@ -277,24 +305,29 @@ def _run_stack(params, rparams, x, *, cfg, spec, pol, mode, period, causal,
                                  (g if isinstance(g, tuple) else (g,))),
             check_vma=False)
 
-    fns = []
-    for ent in period:
-        f = shard_block(partial(apply_block, ent))
-        if remat:
-            f = jax.checkpoint(f)
-        fns.append(f)
+    fns = {}
+    for ent in pat.lead + period + pat.tail:
+        if ent not in fns:
+            f = shard_block(partial(apply_block, ent))
+            fns[ent] = jax.checkpoint(f) if remat else f
 
     has_rp = rparams is not None and mode != "base"
+    plan = partial(_unrolled, params=params, rparams=rparams, pat=pat,
+                   pol_split=pol_split, has_rp=has_rp,
+                   static_pol=static_pol, pol=pol)
+    for lp, ent, lrp, lpol in plan("lead"):
+        x, a = fns[ent](lp, lrp, lpol, x, enc_kv, enc_valid)
+        aux = aux + a
 
     def body(carry, xs):
         x, aux = carry
         lps = xs["p"]
         lrps = xs["r"] if has_rp else [None] * len(period)
         lpols = xs.get("pol")
-        for j in range(len(period)):
+        for j, ent in enumerate(period):
             lpol = lpols[j] if lpols is not None else \
                 (None if static_pol else pol)
-            x, a = fns[j](lps[j], lrps[j], lpol, x, enc_kv, enc_valid)
+            x, a = fns[ent](lps[j], lrps[j], lpol, x, enc_kv, enc_valid)
             aux = aux + a
         return (x, aux), None
 
@@ -307,14 +340,10 @@ def _run_stack(params, rparams, x, *, cfg, spec, pol, mode, period, causal,
         if has_rp:
             xs["r"] = rparams["scan"]
         if layered:
-            xs["pol"] = pol_scan
-        (x, aux), _ = jax.lax.scan(body, (x, aux0), xs)
-    else:
-        aux = aux0
-    for i, (lp, _ent, lrp, lpol) in enumerate(_tail_plan(
-            params, rparams, period, pol_tail, has_rp=has_rp,
-            static_pol=static_pol, pol=pol)):
-        x, a = fns[i % len(period)](lp, lrp, lpol, x, enc_kv, enc_valid)
+            xs["pol"] = pol_split["scan"]
+        (x, aux), _ = jax.lax.scan(body, (x, aux), xs)
+    for lp, ent, lrp, lpol in plan("tail"):
+        x, a = fns[ent](lp, lrp, lpol, x, enc_kv, enc_valid)
         aux = aux + a
     return x, aux
 
@@ -343,15 +372,16 @@ def _context(params, rparams, batch, cfg, spec, pol, mode, remat=False):
         enc_p = params["encoder"]
         enc_rp = rparams.get("encoder") if (rparams and mode != "base") else None
         x = batch["frames"].astype(dtype_of(cfg)) @ enc_p["in_proj"]
-        period, _, _ = build_pattern(cfg.encoder, spec)
+        pat = build_pattern(cfg.encoder, spec)
         # NOTE: no `bucket` here — the caller's bucket is solved for the
         # DECODER sequence length; an undersized bucket would silently drop
         # selected encoder tokens. Traced encoder capacities take the dense
         # fallback (static ones still derive their own bucket inline).
         x, aux = _run_stack(enc_p, enc_rp, x, cfg=cfg.encoder, spec=spec,
-                            pol=pol, mode=mode, period=period, causal=False,
+                            pol=pol, mode=mode, pat=pat, causal=False,
                             remat=remat)
-        x = norm_apply(enc_p["final_norm"], x, cfg.encoder.norm)
+        x = norm_apply(enc_p["final_norm"], x, cfg.encoder.norm,
+                       cfg.encoder.norm_eps)
         x, valid = select_context_tokens(rparams, x, spec, pol, mode) \
             if spec is not None else (x, None)
         return x, valid, aux
@@ -376,39 +406,96 @@ def forward(params, rparams, batch, cfg, ecfg=None, mode: str = "base",
     math (attention softmax core, fused MLP, MoE grouped matmul) executes
     through the Pallas kernels or the jnp twins — see kernels/ops.py."""
     spec, pol = as_spec_policy(ecfg, policy)
-    period, _, _ = build_pattern(cfg, spec)
+    pat = build_pattern(cfg, spec)
     if cfg.family == "encoder":
         x = batch["embeds"].astype(dtype_of(cfg)) @ params["in_proj"]
         rp = rparams if mode != "base" else None
         x, aux = _run_stack(params, rp, x, cfg=cfg, spec=spec, pol=pol,
-                            mode=mode, period=period, causal=False,
+                            mode=mode, pat=pat, causal=False,
                             remat=remat, bucket=bucket)
-        return norm_apply(params["final_norm"], x, cfg.norm), aux
+        return (norm_apply(params["final_norm"], x, cfg.norm, cfg.norm_eps),
+                aux)
     enc_kv, enc_valid, aux0 = _context(params, rparams, batch, cfg, spec,
                                        pol, mode, remat)
     x = _embed(params, cfg, batch["tokens"])
     rp = rparams if mode != "base" else None
     x, aux = _run_stack(params, rp, x, cfg=cfg, spec=spec, pol=pol, mode=mode,
-                        period=period, causal=True, enc_kv=enc_kv,
+                        pat=pat, causal=True, enc_kv=enc_kv,
                         enc_valid=enc_valid, remat=remat, bucket=bucket)
     aux = aux + aux0
     with jax.named_scope("lm_head"):
-        x = norm_apply(params["final_norm"], x, cfg.norm)
+        x = norm_apply(params["final_norm"], x, cfg.norm, cfg.norm_eps)
         out = x if return_hidden else _logits(params, cfg, x)
     return out, aux
 
 
 # ------------------------------ serving --------------------------------------
 
+def _walk(step, x, params, rparams, cfg, pat, pol, mode, caches=None):
+    """Run ``step(ent, lp, lrp, lpol, x, lc) -> (x, new layer cache)``
+    over the lead layers, the scanned period and the tail, with each
+    layer's params, router params, policy and (when ``caches`` is given)
+    cache. Returns (x, the new caches in the params' layout)."""
+    static_pol = _pol_static(pol)
+    layered = (not static_pol) and pol.has_layer_dim
+    pol_split = _split_policy(pol, cfg.n_layers, pat) if layered else None
+    has_rp = rparams is not None and mode != "base"
+    eff = (lambda lpol: pol) if static_pol else \
+        (lambda lpol: pol if lpol is None else lpol)
+    out = {}
+
+    def unrolled(part, x):
+        lcs = caches[part] if caches is not None else None
+        ncs = []
+        for i, (lp, ent, lrp, lpol) in enumerate(_unrolled(
+                part, params, rparams, pat, pol_split, has_rp=has_rp,
+                static_pol=static_pol, pol=pol)):
+            x, nc = step(ent, lp, lrp, eff(lpol), x,
+                         None if lcs is None else lcs[i])
+            ncs.append(nc)
+        return x, ncs
+
+    if "lead" in params:
+        x, out["lead"] = unrolled("lead", x)
+
+    def body(x, xs):
+        lps = xs["p"]
+        lrps = xs["r"] if has_rp else [None] * len(pat.period)
+        lcs = xs["c"] if caches is not None else [None] * len(pat.period)
+        lpols = xs.get("pol")
+        ncs = []
+        for j, ent in enumerate(pat.period):
+            lpol = lpols[j] if lpols is not None else None
+            x, nc = step(ent, lps[j], lrps[j], eff(lpol), x, lcs[j])
+            ncs.append(nc)
+        return x, ncs
+
+    if params["scan"]:
+        assert len(params["scan"]) == len(pat.period), (
+            f"param stacking period ({len(params['scan'])}) != apply-time "
+            f"pattern period ({len(pat.period)}): init and apply must use "
+            f"the same elastic layers mode")
+        xs = {"p": params["scan"]}
+        if has_rp:
+            xs["r"] = rparams["scan"]
+        if caches is not None:
+            xs["c"] = caches["scan"]
+        if layered:
+            xs["pol"] = pol_split["scan"]
+        x, out["scan"] = jax.lax.scan(body, x, xs)
+    else:
+        out["scan"] = []
+    x, out["tail"] = unrolled("tail", x)
+    return x, out
+
+
 def cache_init(cfg, batch: int, max_seq: int, kv_dtype: str = "fp32"):
-    period, P, _ = build_pattern(cfg, None)
     enc_len = cfg.n_image_tokens or cfg.encoder_seq
     caches = [block_cache_init(k, cfg, batch, max_seq, enc_len,
                                window=cfg.layer_windows[i],
                                kv_dtype=kv_dtype)
               for i, k in enumerate(cfg.layer_kinds)]
-    scan, tail = _split_layers(caches, len(period), P)
-    return {"scan": scan, "tail": tail}
+    return _split_layers(caches, build_pattern(cfg, None))
 
 
 def prefill(params, rparams, batch, cfg, ecfg=None, mode: str = "infer",
@@ -416,58 +503,25 @@ def prefill(params, rparams, batch, cfg, ecfg=None, mode: str = "infer",
     """Forward + cache collection. Returns (logits_last (B,V), caches).
     ``bucket``: static ragged capacity-bucket hint (train-mode prefill)."""
     spec, pol = as_spec_policy(ecfg, policy)
-    period, P, _ = build_pattern(cfg, spec)
+    pat = build_pattern(cfg, spec)
     enc_kv, enc_valid, _ = _context(params, rparams, batch, cfg, spec, pol,
                                     mode)
     x = _embed(params, cfg, batch["tokens"])
-    S = x.shape[1]
-    L = max_cache_len or S
-    has_rp = rparams is not None and mode != "base"
-    static_pol = _pol_static(pol)
-    layered = (not static_pol) and pol.has_layer_dim
-    pol_scan = pol_tail = None
-    if layered:
-        pol_scan, pol_tail = _split_policy(pol, cfg.n_layers, len(period), P)
+    L = max_cache_len or x.shape[1]
 
-    def apply_block(ent, lp, lrp, lpol, x):
-        return block_apply(
-            ent.kind, lp, lrp, x, cfg=cfg, spec=spec,
-            pol=(pol if static_pol else lpol), mode=mode,
+    def step(ent, lp, lrp, lpol, x, _lc):
+        x, _, nc = block_apply(
+            ent.kind, lp, lrp, x, cfg=cfg, spec=spec, pol=lpol, mode=mode,
             elastic_on=ent.elastic, window=ent.window, causal=True,
             enc_kv=enc_kv, enc_valid=enc_valid, collect_cache=True,
             max_cache_len=L, bucket=bucket)
+        return x, nc
 
-    def body(x, xs):
-        lps = xs["p"]
-        lrps = xs["r"] if has_rp else [None] * len(period)
-        lpols = xs.get("pol")
-        ncs = []
-        for j, ent in enumerate(period):
-            lpol = lpols[j] if lpols is not None else \
-                (None if static_pol else pol)
-            x, _, nc = apply_block(ent, lps[j], lrps[j], lpol, x)
-            ncs.append(nc)
-        return x, ncs
-
-    if params["scan"]:
-        xs = {"p": params["scan"]}
-        if has_rp:
-            xs["r"] = rparams["scan"]
-        if layered:
-            xs["pol"] = pol_scan
-        x, scan_caches = jax.lax.scan(body, x, xs)
-    else:
-        scan_caches = []
-    tail_caches = []
-    for lp, ent, lrp, lpol in _tail_plan(
-            params, rparams, period, pol_tail, has_rp=has_rp,
-            static_pol=static_pol, pol=pol):
-        x, _, nc = apply_block(ent, lp, lrp, lpol, x)
-        tail_caches.append(nc)
+    x, caches = _walk(step, x, params, rparams, cfg, pat, pol, mode)
     with jax.named_scope("lm_head"):
-        x = norm_apply(params["final_norm"], x, cfg.norm)
+        x = norm_apply(params["final_norm"], x, cfg.norm, cfg.norm_eps)
         logits = _logits(params, cfg, x[:, -1])
-    return logits, {"scan": scan_caches, "tail": tail_caches}
+    return logits, caches
 
 
 def cache_insert(caches, row_caches, slot, cfg=None):
@@ -479,12 +533,10 @@ def cache_insert(caches, row_caches, slot, cfg=None):
     shardings (kv-heads over `model`, slots over data) — the row update is
     a batch-dim dynamic_update_slice, which GSPMD would otherwise resolve
     by replicating the whole live cache."""
-    out = {
-        "scan": [cache_row_insert(f, r, slot, batch_axis=1)
-                 for f, r in zip(caches["scan"], row_caches["scan"])],
-        "tail": [cache_row_insert(f, r, slot, batch_axis=0)
-                 for f, r in zip(caches["tail"], row_caches["tail"])],
-    }
+    out = {part: [cache_row_insert(f, r, slot,
+                                   batch_axis=1 if part == "scan" else 0)
+                  for f, r in zip(caches[part], row_caches[part])]
+           for part in caches}
     if cfg is not None:
         from repro.runtime import sharding as SH
         out = SH.constrain_cache_tree(out, cfg)
@@ -525,54 +577,20 @@ def decode_step(params, rparams, token, caches, t, cfg, ecfg=None,
     same page ids, so the table rides the scan as a loop-invariant capture
     (never stacked into xs)."""
     spec, pol = as_spec_policy(ecfg, policy)
-    period, P, _ = build_pattern(cfg, spec)
+    pat = build_pattern(cfg, spec)
     x = _embed(params, cfg, token)
-    has_rp = rparams is not None and mode != "base"
-    static_pol = _pol_static(pol)
-    layered = (not static_pol) and pol.has_layer_dim
-    pol_scan = pol_tail = None
-    if layered:
-        pol_scan, pol_tail = _split_policy(pol, cfg.n_layers, len(period), P)
 
-    def body(x, xs):
-        lps, lcs = xs["p"], xs["c"]
-        lrps = xs["r"] if has_rp else [None] * len(period)
-        lpols = xs.get("pol")
-        ncs = []
-        for j, ent in enumerate(period):
-            lpol = lpols[j] if lpols is not None else \
-                (None if static_pol else pol)
-            x, nc = block_decode(
-                ent.kind, lps[j], lrps[j], x, lcs[j], t, cfg=cfg, spec=spec,
-                pol=(pol if static_pol else lpol), mode=mode,
-                elastic_on=ent.elastic, window=ent.window,
-                table=table, trash=trash)
-            ncs.append(nc)
-        return x, ncs
+    def step(ent, lp, lrp, lpol, x, lc):
+        return block_decode(
+            ent.kind, lp, lrp, x, lc, t, cfg=cfg, spec=spec, pol=lpol,
+            mode=mode, elastic_on=ent.elastic, window=ent.window,
+            table=table, trash=trash)
 
-    if params["scan"]:
-        xs = {"p": params["scan"], "c": caches["scan"]}
-        if has_rp:
-            xs["r"] = rparams["scan"]
-        if layered:
-            xs["pol"] = pol_scan
-        x, new_scan = jax.lax.scan(body, x, xs)
-    else:
-        new_scan = []
-    new_tail = []
-    for i, (lp, ent, lrp, lpol) in enumerate(_tail_plan(
-            params, rparams, period, pol_tail, has_rp=has_rp,
-            static_pol=static_pol, pol=pol)):
-        x, nc = block_decode(ent.kind, lp, lrp, x, caches["tail"][i], t,
-                             cfg=cfg, spec=spec,
-                             pol=(pol if static_pol else lpol), mode=mode,
-                             elastic_on=ent.elastic, window=ent.window,
-                             table=table, trash=trash)
-        new_tail.append(nc)
+    x, caches = _walk(step, x, params, rparams, cfg, pat, pol, mode, caches)
     with jax.named_scope("lm_head"):
-        x = norm_apply(params["final_norm"], x, cfg.norm)
+        x = norm_apply(params["final_norm"], x, cfg.norm, cfg.norm_eps)
         logits = _logits(params, cfg, x[:, -1])
-    return logits, {"scan": new_scan, "tail": new_tail}
+    return logits, caches
 
 
 # --------------------------- paged serving -----------------------------------
@@ -582,12 +600,10 @@ def paged_cache_init(cfg, n_pages: int, page_size: int,
     """Paged twin of ``cache_init``: per-layer slices of the GLOBAL page
     pool, stacked into the same scan/tail pattern tree (scan leaves gain a
     leading period dim). Attention-only — validated per layer kind."""
-    period, P, _ = build_pattern(cfg, None)
     caches = [block_paged_cache_init(k, cfg, n_pages, page_size,
                                      kv_dtype=kv_dtype)
               for k in cfg.layer_kinds]
-    scan, tail = _split_layers(caches, len(period), P)
-    return {"scan": scan, "tail": tail}
+    return _split_layers(caches, build_pattern(cfg, None))
 
 
 def prefill_chunk_step(params, rparams, tokens, caches, write_page, table_row,
@@ -604,57 +620,23 @@ def prefill_chunk_step(params, rparams, tokens, caches, write_page, table_row,
     Returns (logits (1, V) at the chunk's LAST REAL position — only the
     final chunk's logits feed sampling — and the new caches)."""
     spec, pol = as_spec_policy(ecfg, policy)
-    period, P_, _ = build_pattern(cfg, spec)
+    pat = build_pattern(cfg, spec)
     x = _embed(params, cfg, tokens)
-    has_rp = rparams is not None and mode != "base"
-    static_pol = _pol_static(pol)
-    layered = (not static_pol) and pol.has_layer_dim
-    pol_scan = pol_tail = None
-    if layered:
-        pol_scan, pol_tail = _split_policy(pol, cfg.n_layers, len(period), P_)
 
-    def body(x, xs):
-        lps, lcs = xs["p"], xs["c"]
-        lrps = xs["r"] if has_rp else [None] * len(period)
-        lpols = xs.get("pol")
-        ncs = []
-        for j, ent in enumerate(period):
-            lpol = lpols[j] if lpols is not None else \
-                (None if static_pol else pol)
-            x, nc = block_chunk(
-                ent.kind, lps[j], lrps[j], x, lcs[j], write_page, table_row,
-                pos0, plen, cfg=cfg, spec=spec,
-                pol=(pol if static_pol else lpol), mode=mode,
-                elastic_on=ent.elastic)
-            ncs.append(nc)
-        return x, ncs
+    def step(ent, lp, lrp, lpol, x, lc):
+        return block_chunk(
+            ent.kind, lp, lrp, x, lc, write_page, table_row, pos0, plen,
+            cfg=cfg, spec=spec, pol=lpol, mode=mode, elastic_on=ent.elastic)
 
-    if params["scan"]:
-        xs = {"p": params["scan"], "c": caches["scan"]}
-        if has_rp:
-            xs["r"] = rparams["scan"]
-        if layered:
-            xs["pol"] = pol_scan
-        x, new_scan = jax.lax.scan(body, x, xs)
-    else:
-        new_scan = []
-    new_tail = []
-    for i, (lp, ent, lrp, lpol) in enumerate(_tail_plan(
-            params, rparams, period, pol_tail, has_rp=has_rp,
-            static_pol=static_pol, pol=pol)):
-        x, nc = block_chunk(ent.kind, lp, lrp, x, caches["tail"][i],
-                            write_page, table_row, pos0, plen, cfg=cfg,
-                            spec=spec, pol=(pol if static_pol else lpol),
-                            mode=mode, elastic_on=ent.elastic)
-        new_tail.append(nc)
+    x, caches = _walk(step, x, params, rparams, cfg, pat, pol, mode, caches)
     with jax.named_scope("lm_head"):
-        x = norm_apply(params["final_norm"], x, cfg.norm)
+        x = norm_apply(params["final_norm"], x, cfg.norm, cfg.norm_eps)
         lidx = jnp.clip(jnp.asarray(plen, jnp.int32) - 1
                         - jnp.asarray(pos0, jnp.int32), 0, x.shape[1] - 1)
         h_last = jax.lax.dynamic_index_in_dim(x, lidx, axis=1,
                                               keepdims=False)
         logits = _logits(params, cfg, h_last)
-    return logits, {"scan": new_scan, "tail": new_tail}
+    return logits, caches
 
 
 # ------------------------------ input specs ----------------------------------

@@ -1,11 +1,24 @@
-"""Mixture-of-experts MLP: native (qwen2-moe, grok-1) and ElastiFormer's
-moefied dense MLP share this machinery.
+"""Mixture-of-experts MLP: native (qwen2-moe, grok-1, lfm2-moe) and
+ElastiFormer's moefied dense MLP share this machinery.
 
 Dispatch is per-expert capacity gather (exact top-k semantics, FLOPs
 proportional to selected experts only, no (B,S,E,C) one-hot): for each expert
 take its top-C tokens by routing weight, gather, batched expert matmul,
 weighted scatter-add. Sequence-chunked via lax.scan to bound the gather
 buffer and keep the HLO small at 512-way SPMD.
+
+Native experts dispatch dropless (C = the chunk's rows: no token loses an
+expert it picked, so a prompt's result does not depend on how it is
+chunked); the grouped-matmul kernel's per-expert counts skip the empty
+capacity tiles. Moefied experts keep the capacity factor.
+
+Native routers: ``softmax`` (scores ``s = softmax(h W_r)``, the top-k of
+``s``, each weighted by its ``s``) or ``sigmoid`` (lfm2-moe: ``s =
+sigmoid(h W_r)``, the top-k of ``s + expert_bias``, each weighted by its
+``s`` renormalised over the chosen experts). The elastic expert knob
+keeps the top ``count`` of those k. The moefied
+router (``router=None``) is the ElastiFormer one: ``E * softmax``, and a
+count >= E runs the dense module.
 """
 from __future__ import annotations
 
@@ -33,6 +46,8 @@ def moe_init(key, cfg):
         "wi": dense_init(ks[1], D, E * Fe, dt).reshape(D, E, Fe).transpose(1, 0, 2),
         "wo": dense_init(ks[2], Fe, E * D, dt).reshape(Fe, E, D).transpose(1, 0, 2),
     }
+    if m.router == "sigmoid":
+        p["expert_bias"] = jnp.zeros((E,), jnp.float32)
     if is_gated(cfg.act):
         p["wg"] = dense_init(ks[3], D, E * Fe, dt).reshape(D, E, Fe).transpose(1, 0, 2)
     if m.n_shared_experts:
@@ -70,13 +85,60 @@ def _expert_ffn(p, x_sel, act, backend=None, counts=None):
                       quant.maybe_dequant(p, "wo", x_sel.dtype)).astype(x_sel.dtype)
 
 
+def _router_logits(x, rw, native: bool):
+    """Router logits in float32; a native router's at full float32
+    precision (its top-k picks experts outright, so a rounded logit can
+    swap an expert for another)."""
+    x = x.astype(jnp.float32)
+    if native:
+        return jnp.dot(x, rw, precision=jax.lax.Precision.HIGHEST)
+    return x @ rw
+
+
+def _native_scores(p, logits, router: str):
+    """(scores ``s``, selection scores) of a native router."""
+    if router == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+        return s, s + p["expert_bias"]
+    s = jax.nn.softmax(logits, axis=-1)
+    return s, s
+
+
+def _native_weights(s, mask, router: str):
+    """Combine weights of the chosen experts: ``s``, renormalised over the
+    ``mask``ed ones (+1e-6) by the sigmoid router; 0 elsewhere."""
+    w = jnp.where(mask, s, 0.0)
+    if router == "sigmoid":
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+    return w
+
+
+def _native_select(sel, k: int, top_k_traced):
+    """Experts chosen by selection score ``sel``: the top ``k``; with a
+    traced elastic count below ``k``, the top ``count`` of them (>= k:
+    exactly the native top-k)."""
+    mask = topk_mask(sel, k)
+    if top_k_traced is None:
+        return mask
+    full = bcast_to(is_full(top_k_traced, k), sel.ndim)
+    part = topk_mask_dyn(sel, jnp.clip(top_k_traced, 1, k))
+    return jnp.where(full, mask, part)
+
+
 @jax.named_scope("mlp")
 def moe_apply(
     p, x, *, act: str, top_k: int, router_w=None, normalize_to_m: bool = False,
     capacity_factor: float = 1.25, seq_chunk: int = 2048, top_k_traced=None,
     token_valid=None, dispatch_frac=None, token_count=None, backend=None,
+    router=None,
 ):
     """x: (B,S,D) -> (B,S,D), aux. router_w overrides p['router'] (elastic).
+
+    ``router`` "softmax"/"sigmoid": a native router (module docstring),
+    dispatched dropless: capacity is the chunk's rows, so every chosen
+    (token, expert) pair is computed. ``top_k_traced`` then keeps the top
+    ``count`` of the ``top_k`` chosen experts (>= top_k: the native
+    selection exactly).
 
     ``top_k_traced``: optional traced expert count ((), or (B,)). Dispatch
     buffers are then sized for ``top_k`` (the static maximum — pass E for
@@ -118,22 +180,34 @@ def moe_apply(
     if token_valid is not None:
         tv = token_valid if s_pad == S else jnp.pad(
             token_valid, [(0, 0), (0, s_pad - S)])
-    cap = int(math.ceil(k * chunk / E * capacity_factor))
-    cap = min(chunk, max(4, -(-cap // 4) * 4))
+    dropless = router is not None
+    if dropless:
+        cap = chunk
+    else:
+        cap = int(math.ceil(k * chunk / E * capacity_factor))
+        cap = min(chunk, max(4, -(-cap // 4) * 4))
 
     def one_chunk(xc, vc, tvc):
         s = xc.shape[1]
         with jax.named_scope("router"):
-            logits = xc.astype(jnp.float32) @ rw                  # (B,s,E)
-            probs = jax.nn.softmax(logits, axis=-1)
-            w = probs * E if normalize_to_m else probs
+            logits = _router_logits(xc, rw, router is not None)  # (B,s,E)
             cap_eff = None
             kept = chunk if dispatch_frac is None else jnp.clip(
                 jnp.ceil(dispatch_frac * chunk - 1e-9), 1, chunk)
-            if top_k_traced is None:
+            if router is not None:
+                probs, sel = _native_scores(p, logits, router)
+                mask = _native_select(sel, k, top_k_traced) \
+                    & vc[None, :, None]
+                w = _native_weights(probs, mask, router)
+                k_for_cap = k
+            elif top_k_traced is None:
+                probs = jax.nn.softmax(logits, axis=-1)
+                w = probs * E if normalize_to_m else probs
                 mask = topk_mask(w, k) & vc[None, :, None]
                 k_for_cap = k
             else:
+                probs = jax.nn.softmax(logits, axis=-1)
+                w = probs * E if normalize_to_m else probs
                 kt = jnp.clip(top_k_traced, 1, E)
                 full = bcast_to(is_full(top_k_traced, E), w.ndim)
                 w = jnp.where(full, 1.0, w)
@@ -141,7 +215,8 @@ def moe_apply(
                 k_for_cap = kt
             if tvc is not None:
                 mask = mask & tvc[:, :, None]
-            if top_k_traced is not None or dispatch_frac is not None:
+            if not dropless and (top_k_traced is not None
+                                 or dispatch_frac is not None):
                 # per-expert capacity the static path would have compiled for
                 # this budget (buffers stay sized for the static maximum `cap`)
                 ce = jnp.ceil(k_for_cap * kept / E * capacity_factor)
@@ -220,23 +295,38 @@ def _dense_ffn(p, x, act):
 
 @jax.named_scope("mlp")
 def moe_decode(p, x, *, act: str, top_k: int, router_w=None,
-               normalize_to_m: bool = False, top_k_traced=None):
+               normalize_to_m: bool = False, top_k_traced=None,
+               router=None):
     """Decode path (S==1): gather only the selected experts' weights so HBM
     traffic ∝ top-k experts (memory-roofline critical at 314B scale).
 
     With ``top_k_traced`` the gather covers the static ``top_k`` maximum and
     experts ranked beyond the traced count get weight 0 (>= E: all weight 1,
-    the exact dense module) — variable expert budgets on one graph."""
+    the exact dense module) — variable expert budgets on one graph. A
+    native ``router`` gathers its top ``top_k``; a traced count keeps the
+    first ``count`` of them (>= top_k: the native weights exactly)."""
     B, S, D = x.shape
     rw = router_w if router_w is not None else p["router"]
     E = rw.shape[-1]
     k = min(top_k, E)
     with jax.named_scope("router"):
-        logits = x.astype(jnp.float32) @ rw                   # (B,1,E)
-        probs = jax.nn.softmax(logits, axis=-1)
-        w = probs * E if normalize_to_m else probs
-        vals, idx = jax.lax.top_k(w[:, 0], k)                 # (B,k)
-        if top_k_traced is not None:
+        logits = _router_logits(x, rw, router is not None)    # (B,1,E)
+        if router is not None:
+            s, sel = _native_scores(p, logits[:, 0], router)  # (B,E)
+            idx = jax.lax.top_k(sel, k)[1]                    # (B,k)
+            s_k = jnp.take_along_axis(s, idx, axis=-1)
+            vals = _native_weights(s_k, True, router)
+            if top_k_traced is not None:
+                kept = jnp.arange(k)[None, :] < bcast_to(
+                    jnp.clip(top_k_traced, 1, k), 2)
+                full = bcast_to(is_full(top_k_traced, k), 2)
+                vals = jnp.where(full, vals,
+                                 _native_weights(s_k, kept, router))
+        else:
+            probs = jax.nn.softmax(logits, axis=-1)
+            w = probs * E if normalize_to_m else probs
+            vals, idx = jax.lax.top_k(w[:, 0], k)             # (B,k)
+        if top_k_traced is not None and router is None:
             kt = jnp.clip(top_k_traced, 1, E)
             sel = jnp.arange(k)[None, :] < bcast_to(kt, 2)    # (B,k)
             full = bcast_to(is_full(top_k_traced, E), 2)

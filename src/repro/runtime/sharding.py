@@ -88,6 +88,9 @@ _RULES = [
     (r"\['w_[ai]'\]$", 2, P(None, "model")),           # (W, W) col-sharded
     (r"\['(b_a|b_i|lam)'\]$", 1, P("model")),
     (r"\['w_out'\]$", 2, P("model", None)),            # (W, D)
+    # lfm2 short conv: in_proj (D, 3, D) -> B | C | x, each split over D;
+    # conv_w (L, D) by the rg-lru rule; out_proj (D, D) by the mamba2 rule
+    (r"\['mixer'\]\['in_proj'\]$", 3, P(None, None, "model")),
     # frontends
     (r"\['in_proj'\]$", 2, P()),
 ]
@@ -275,6 +278,8 @@ def cache_specs_tree(cache_shapes, cfg, mesh: Mesh):
             return P(*lead, ba, "model")
         if key.endswith("['conv']"):
             return P(*lead, ba, None, None)
+        if key.endswith("['bx']"):                  # lfm2 short-conv rows
+            return P(*lead, ba, None, "model")
         return P(*lead, ba)
 
     flat, treedef = jax.tree_util.tree_flatten_with_path(cache_shapes)

@@ -42,8 +42,9 @@ Under the JAX profiler ``step()`` records its phases as host spans
 the ``pages`` of their tables the decode kernel visits, ``serve.sync``,
 ``serve.emit``), and
 every compiled operation carries the model's named scope (``attention``,
-``mlp``, ``router``, ``lm_head``, ``sample``) in its metadata. With the
-profiler off a span costs about a microsecond and scopes cost nothing.
+``conv``, ``mlp``, ``router``, ``lm_head``, ``sample``) in its metadata.
+With the profiler off a span costs about a microsecond and scopes cost
+nothing.
 """
 from __future__ import annotations
 
@@ -327,18 +328,21 @@ class ServingEngine:
     def _validate_paged(self, mode: str) -> None:
         """The paged subsystem serves the elastic decoder hot path: global
         self-attention layers with dense MLPs. Windows would need
-        page-eviction semantics, recurrent mixers have no paged state, and
-        MoE/moefied expert dispatch sizes its capacity buffers by the
-        sequence chunking — the one sub-block whose chunked and one-shot
-        prefills can drop different tokens, which would break the paged ==
-        ring token-parity contract."""
+        page-eviction semantics, and recurrent mixers (``ssm``, ``rglru``,
+        ``conv``) carry per-slot state that has no paged form. Moefied
+        expert dispatch sizes its capacity buffers by the sequence
+        chunking — the one sub-block whose chunked and one-shot prefills
+        can drop different tokens, which would break the paged == ring
+        token-parity contract. Native experts are dropless, but no paged
+        == ring test covers them yet, so they stay refused too."""
         if mode not in ("infer", "base"):
             raise ValueError(f"kv_layout='paged' serves infer/base modes, "
                              f"got mode={mode!r}")
         bad = [k for k in self.cfg.layer_kinds if k != "attn"]
         if bad:
             raise ValueError(f"kv_layout='paged' requires all-'attn' layer "
-                             f"kinds, got {sorted(set(bad))}")
+                             f"kinds; recurrent mixers (ssm, rglru, conv) "
+                             f"have no paged state; got {sorted(set(bad))}")
         if any(w and w > 0 for w in self.cfg.layer_windows):
             raise ValueError("kv_layout='paged' does not support sliding-"
                              "window layers")
@@ -348,8 +352,10 @@ class ServingEngine:
         if self.cfg.moe is not None or (self.spec is not None
                                         and self.spec.mlp_n_experts):
             raise ValueError("kv_layout='paged' requires a dense MLP (no "
-                             "MoE / moefied experts): expert-capacity "
-                             "buffers depend on the prefill chunking")
+                             "MoE / moefied experts): moefied expert-"
+                             "capacity buffers depend on the prefill "
+                             "chunking, and native experts have no paged "
+                             "== ring test yet")
         if self.page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {self.page_size}")
 
